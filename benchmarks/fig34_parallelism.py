@@ -1,83 +1,79 @@
 """Paper Figures 3-4: effect of threads / workers on runtime.
 
 Giraph's threads-per-worker and worker count both map to device-mesh size
-here.  We sweep the edge-shard count of the distributed DHLP-2 engine on
-fabricated host devices in SUBPROCESSES (device count is locked at jax
-init, and only the dry-run may fabricate devices in-process).
+here.  We sweep the edge-shard count of the distributed DHLP-2 engine over
+meshes of the first *k* of ``jax.devices()``, all in the process that
+holds the devices (a chip belongs to one process, so a child process
+could not reach it).  A device count the host lacks becomes an explicit
+error record.  On CPU the devices are virtual (``benchmarks/run.py`` and
+``python -m repro`` fabricate 4, or 8 under ``--full``).
 
-On this 1-core container the sweep measures BSP coordination overhead
+On a 1-core CPU host the sweep measures BSP coordination overhead
 (more shards = more rendezvous on the same core) rather than speedup —
-the shape of fig. 3's right half (too many threads slow down).  The
-harness is the deliverable; on a real pod the same sweep spans chips.
-Additionally, a stale-sync sweep shows the straggler-mitigation trade
-(collective count vs iterations) from DESIGN.md §6.
+the shape of fig. 3's right half (too many threads slow down); on a TPU
+host the same sweep spans chips.  Additionally, a stale-sync sweep shows
+the straggler-mitigation trade (collective count vs iterations) from
+DESIGN.md §6.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
 from typing import Dict, List
 
 from repro.bench import BenchRecord, register_suite, stats_from_samples
 
-_CHILD = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(dev)d"
-sys.path.insert(0, %(src)r)
-import numpy as np, jax
-from repro.core import HeteroNetwork, LPConfig
-from repro.data.drugnet import DrugNetSpec, make_drugnet
-from repro.parallel.lp_sharded import ShardedHeteroLP
-from repro.parallel.hints import make_mesh_compat
 
-dn = make_drugnet(DrugNetSpec(n_drug=48, n_disease=32, n_target=24,
-                              n_clusters=6, seed=0))
-norm = dn.network.normalize()
-mesh = make_mesh_compat((1, %(dev)d), ("data", "model"))
-cfg = LPConfig(alg="dhlp2", seed_mode="fixed", sigma=1e-5)
-solver = ShardedHeteroLP(cfg, stale_sync=%(stale)d)
-r = solver.run(norm, mesh)   # compile+run
-t0 = time.time()
-r = solver.run(norm, mesh)
-dt = time.time() - t0
-print(json.dumps({"devices": %(dev)d, "stale": %(stale)d,
-                  "seconds": dt, "iters": int(r.outer_iters),
-                  "converged": bool(r.converged)}))
-"""
+def _run_one(norm, devices, stale: int) -> Dict:
+    from repro.core import LPConfig
+    from repro.parallel.hints import auto_mesh
+    from repro.parallel.lp_sharded import ShardedHeteroLP
 
-
-def _run_child(devices: int, stale: int, src: str) -> Dict:
-    code = _CHILD % {"dev": devices, "stale": stale, "src": src}
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        timeout=600,
-    )
-    for line in reversed(out.stdout.splitlines()):
-        try:
-            return json.loads(line)
-        except json.JSONDecodeError:
-            continue
-    raise RuntimeError(out.stderr[-2000:])
+    k = len(devices)
+    mesh = auto_mesh((1, k), ("data", "model"), devices=devices)
+    cfg = LPConfig(alg="dhlp2", seed_mode="fixed", sigma=1e-5)
+    solver = ShardedHeteroLP(cfg, stale_sync=stale)
+    solver.run(norm, mesh)  # compile + run
+    t0 = time.perf_counter()
+    r = solver.run(norm, mesh)
+    return {
+        "devices": k,
+        "stale": stale,
+        "seconds": time.perf_counter() - t0,
+        "iters": int(r.outer_iters),
+        "converged": bool(r.converged),
+    }
 
 
 def run(device_counts=(1, 2, 4), stale_syncs=(1, 4)) -> List[Dict]:
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    src = os.path.abspath(src)
+    import jax
+
+    from repro.data.drugnet import DrugNetSpec, make_drugnet
+
+    dn = make_drugnet(
+        DrugNetSpec(n_drug=48, n_disease=32, n_target=24, n_clusters=6, seed=0)
+    )
+    norm = dn.network.normalize()
+    available = jax.devices()
     rows = []
     for dev in device_counts:
         for stale in stale_syncs:
+            if dev > len(available):
+                rows.append({
+                    "devices": dev, "stale": stale,
+                    "error": f"host has {len(available)} devices, "
+                             f"the cell needs {dev}",
+                })
+                continue
             try:
-                rows.append(_run_child(dev, stale, src))
-            except Exception as e:  # noqa: BLE001
+                rows.append(_run_one(norm, available[:dev], stale))
+            except Exception as e:  # noqa: BLE001 — one cell, one error row
                 rows.append({"devices": dev, "stale": stale,
-                             "error": str(e)[:200]})
+                             "error": f"{type(e).__name__}: {e}"[:200]})
     return rows
 
 
 @register_suite("fig34_parallelism",
-                description="paper Figs 3-4: worker-count sweep (subprocess)")
+                description="paper Figs 3-4: worker-count sweep over a device mesh")
 def records(fast: bool = True) -> List[BenchRecord]:
     rows = run(device_counts=(1, 2) if fast else (1, 2, 4, 8),
                stale_syncs=(1,) if fast else (1, 4))
@@ -110,5 +106,11 @@ def main(fast: bool = True) -> List[str]:
 
 
 if __name__ == "__main__":
+    import os
+
+    # the sweep's virtual CPU devices must exist before the backend starts
+    os.environ.setdefault(
+        "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
+    )
     for line in main(fast=False):
         print(line)

@@ -13,9 +13,9 @@ Three cell groups, all on the blocked-CSR sparse engine:
   then the (memo/disk) hit that every later solve pays.
 
 Each timed cell also carries the analytic roofline terms
-(``benchmarks/roofline.py``): per-round achieved FLOP/s and bandwidth
-vs the hardware-model peaks, with the deterministic FLOP/byte counts
-strict-gated — they change only when the round's math changes.
+(``benchmarks/roofline.py``): per-round achieved FLOP/s and bandwidth,
+their share of the device's published peaks where it has any, with the
+deterministic FLOP/byte counts strict-gated — they change only when the round's math changes.
 """
 from __future__ import annotations
 
@@ -56,8 +56,10 @@ def _roofline_terms(
     cost = lp_round_cost(
         nnz=nnz, num_nodes=num_nodes, s=s, storage_bytes=storage_bytes
     )
+    import jax
+
     round_s = stats.median_s / max(supersteps, 1)
-    out = achieved_vs_peak(round_s, cost)
+    out = achieved_vs_peak(round_s, cost, jax.devices()[0].device_kind)
     out["round_flops"] = cost["flops"]
     out["round_bytes"] = cost["bytes"]
     return out

@@ -13,8 +13,9 @@ exact totals:
 Collective bytes come from the HLO census (top-level vs in-loop buckets;
 the in-loop bucket is multiplied by the trip count).
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16/chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: the per-chip peaks in :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports.  The dry-run prices its programs on
+:data:`DRYRUN_TARGET`, the chip its production meshes describe.
 """
 from __future__ import annotations
 
@@ -22,9 +23,15 @@ import json
 import os
 from typing import Any, Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: "TPU v5 lite" is TPU v5e — source: Google Cloud documentation, "TPU
+#: v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+#: chip-to-chip interconnect over 4 links (50 GB/s per link).  A device
+#: missing here has no peak: nothing is priced against another chip's.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+DRYRUN_TARGET = "TPU v5 lite"
 
 CHIPS = {"single": 256, "multi": 512}
 
@@ -67,9 +74,10 @@ def analyze(rec: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     col = collective_bytes(rec)
     # cost_analysis is the per-device program; totals are ×chips, and both
     # numerator and denominator scale by chips — terms are per-device time.
-    t_compute = cost["flops"] / PEAK_FLOPS
-    t_memory = cost["hbm_bytes"] / HBM_BW
-    t_collective = col["total"] / ICI_BW
+    peak = PEAKS[DRYRUN_TARGET]
+    t_compute = cost["flops"] / peak["flops"]
+    t_memory = cost["hbm_bytes"] / peak["hbm_bw"]
+    t_collective = col["total"] / peak["ici_bw"]
     terms = {
         "compute": t_compute, "memory": t_memory, "collective": t_collective,
     }
@@ -146,7 +154,7 @@ def build_table(path: str = "results/dryrun.jsonl") -> List[Dict[str, Any]]:
 # The dry-run census covers compiled multi-pod programs; the LP kernel
 # variants (benchmarks/kernel_variants.py) instead run live, so their
 # roofline terms come from an analytic per-round cost model evaluated
-# against the measured wall clock.  Same hardware constants, same units.
+# against the measured wall clock, on the device the round ran on.
 
 
 def lp_round_cost(
@@ -172,21 +180,26 @@ def lp_round_cost(
     return {"flops": flops, "bytes": hbytes}
 
 
-def achieved_vs_peak(round_s: float, cost: Dict[str, float]) -> Dict[str, float]:
-    """Achieved FLOP/s and bandwidth vs the hardware-model peaks.
+def achieved_vs_peak(
+    round_s: float, cost: Dict[str, float], device_kind: str
+) -> Dict[str, float]:
+    """Achieved FLOP/s and bandwidth, and their share of the device's peaks.
 
-    ``round_s`` is the measured wall time of one LP round; the fractions
-    are against the same TPU-v5e peaks the dry-run roofline uses (on the
-    CPU CI runner they are diagnostics, not predictions — trend numbers
-    comparable across kernel variants, like the interpret-mode timings).
+    ``round_s`` is the measured wall time of one LP round on a device of
+    ``device_kind``.  The ``frac_peak_*`` shares are given only for a
+    device in :data:`PEAKS`; any other device (the CPU among them) gets
+    the achieved rates alone.
     """
     t = max(round_s, 1e-12)
-    return {
+    out = {
         "achieved_gflops": cost["flops"] / t / 1e9,
         "achieved_gbps": cost["bytes"] / t / 1e9,
-        "frac_peak_flops": cost["flops"] / t / PEAK_FLOPS,
-        "frac_peak_bw": cost["bytes"] / t / HBM_BW,
     }
+    peak = PEAKS.get(device_kind)
+    if peak is not None:
+        out["frac_peak_flops"] = cost["flops"] / t / peak["flops"]
+        out["frac_peak_bw"] = cost["bytes"] / t / peak["hbm_bw"]
+    return out
 
 
 # ---------------------------------------------------------------------------

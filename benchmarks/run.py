@@ -51,6 +51,9 @@ def main(argv=None) -> int:
         import_suite_modules,
         run_bench,
     )
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.list:
         from repro.bench import all_suites

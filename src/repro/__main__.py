@@ -22,4 +22,7 @@ os.environ.setdefault(
 from repro.launch.cli import main  # noqa: E402
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

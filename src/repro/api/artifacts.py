@@ -165,6 +165,12 @@ class ServeArtifact(Artifact):
     #: durability roll-up (empty when the spec declared no ft block):
     #: guarded-batch retries/restores, checkpoint cadence, watermark
     ft: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: queries whose batch failed (the scheduler's failed count); any
+    #: makes ``repro run`` exit nonzero
+    failures: int = 0
+    #: the zipf workload's answered QueryResults, in submission order (in
+    #: memory only — not serialized into the JSON summary)
+    answers: List[Any] = dataclasses.field(default_factory=list)
 
     def summary(self) -> Dict[str, Any]:
         out = super().summary()
@@ -174,6 +180,7 @@ class ServeArtifact(Artifact):
                 "engine": self.engine,
                 "report": self.report,
                 "sample": self.sample,
+                "failures": self.failures,
             }
         )
         if self.slo:

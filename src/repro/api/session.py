@@ -551,6 +551,7 @@ class Session:
         seconds = time.perf_counter() - t0
         sample = report.pop("sample", {})
         report.pop("latencies", None)  # raw samples stay in memory only
+        answers = report.pop("answers", [])
         return ServeArtifact(
             run_id=self.run_id,
             seconds=seconds,
@@ -560,6 +561,8 @@ class Session:
             sample=sample,
             slo=self._watchdog.report() if self._watchdog is not None else {},
             ft=engine.ft_stats(),
+            failures=int(report["failed"]),
+            answers=answers,
         )
 
     # --------------------------------------------------------------- bench

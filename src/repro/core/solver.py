@@ -34,6 +34,7 @@ from repro.core.network import (
     NormalizedNetwork,
     seeds_identity,
 )
+from repro.kernels.common import F32_MATMUL
 
 Algorithm = Literal["dhlp1", "dhlp2"]
 SeedMode = Literal["fixed", "drift"]
@@ -163,11 +164,11 @@ def _dhlp2_step_loop(
         src = Y if seed_mode == "fixed" else F
         # superstep A: heterogeneous injection  y' = βy + αHF
         Yp = beta * src + alpha * jnp.matmul(
-            H, F, preferred_element_type=acc
+            H, F, preferred_element_type=acc, precision=F32_MATMUL
         ).astype(F.dtype)
         # superstep B: homogeneous propagation  f = βy' + αMF
         Fn = beta * Yp + alpha * jnp.matmul(
-            M, F, preferred_element_type=acc
+            M, F, preferred_element_type=acc, precision=F32_MATMUL
         ).astype(F.dtype)
         Fn = jnp.where(active[None, :], Fn, F)  # voteToHalt: freeze
         delta = jnp.max(jnp.abs(Fn - F), axis=0)
@@ -229,7 +230,7 @@ def _dhlp2_fused_loop(
             Fn = lp_round_op(A_eff, F, beta2 * base, c=1.0, use_kernel=True)
         else:
             Fn = beta2 * base + jnp.matmul(
-                A_eff, F, preferred_element_type=acc
+                A_eff, F, preferred_element_type=acc, precision=F32_MATMUL
             ).astype(F.dtype)
         if momentum:
             Fn = Fn + momentum * (F - F_prev)
@@ -290,7 +291,7 @@ def _dhlp1_loop(
         def ibody(istate):
             F, iact, it = istate
             Fn = beta * Yp + alpha * jnp.matmul(
-                M, F, preferred_element_type=acc
+                M, F, preferred_element_type=acc, precision=F32_MATMUL
             ).astype(F.dtype)
             Fn = jnp.where(iact[None, :], Fn, F)
             delta = jnp.max(jnp.abs(Fn - F), axis=0)
@@ -309,7 +310,7 @@ def _dhlp1_loop(
         F, active, it, tot_inner, col_iters = state
         src = Y if seed_mode == "fixed" else F
         Yp = beta * src + alpha * jnp.matmul(
-            H, F, preferred_element_type=acc
+            H, F, preferred_element_type=acc, precision=F32_MATMUL
         ).astype(F.dtype)
         Fn, inner_it = inner(Yp, F, active)
         Fn = jnp.where(active[None, :], Fn, F)

@@ -47,6 +47,9 @@ PANEL_GRID: Tuple[Tuple[int, int, int], ...] = (
     (256, 128, 32),
     (512, 128, 16),
 )
+# The panel sweep times the VMEM-resident kernel; beyond this many nodes
+# the (N, bs) panel outgrows a few MiB of VMEM and the sweep is skipped.
+PANEL_SWEEP_MAX_NODES = 16384
 
 
 @dataclasses.dataclass(frozen=True)
@@ -231,7 +234,7 @@ def _time_panels(bcsr, *, bn, bs, bd, s, repeats):
             m = nbr.shape[0]
             sl = F[off : off + m]
             out, _ = csr_round_residual_op(
-                nbr, wgt, F, sl, sl, c=0.25, bn=bn, bs=bs, bd=bd, use_kernel=True
+                nbr, wgt, F, sl, sl, c=0.25, bn=bn, bs=bs, bd=bd
             )
             outs.append(out)
             off += m
@@ -286,9 +289,7 @@ def ensure_tuned(
             best_t, best_layout, best_bcsr = t, (block_rows, width_mult), bcsr
 
     bn, bs, bd = DEFAULT_PARAMS.bn, DEFAULT_PARAMS.bs, DEFAULT_PARAMS.bd
-    from repro.kernels.segment_reduce.ops import _MAX_RESIDENT_NODES
-
-    if sweep_panels and n <= _MAX_RESIDENT_NODES:
+    if sweep_panels and n <= PANEL_SWEEP_MAX_NODES:
         best_pt = float("inf")
         for cand in PANEL_GRID:
             t = _time_panels(

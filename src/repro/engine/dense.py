@@ -15,6 +15,7 @@ import numpy as np
 from repro.core.network import NormalizedNetwork
 from repro.core.solver import HeteroLP, LPConfig, SolveResult
 from repro.engine.base import LPEngine, Operator, register_backend
+from repro.kernels.common import F32_MATMUL
 
 
 @register_backend("dense")
@@ -62,6 +63,6 @@ class DenseEngine(LPEngine):
         F = jnp.asarray(F, dtype=cfg.dtype)
         Y = jnp.asarray(Y, dtype=cfg.dtype)
         out = beta2 * Y + jnp.matmul(
-            A_eff, F, preferred_element_type=jnp.float32
+            A_eff, F, preferred_element_type=jnp.float32, precision=F32_MATMUL
         ).astype(F.dtype)
         return np.asarray(out, dtype=np.float64)

@@ -46,15 +46,17 @@ class ShardedEngine(LPEngine):
         if self._mesh is None:
             import jax
 
-            from repro.parallel.hints import make_mesh_compat
+            from repro.parallel.hints import auto_mesh
 
-            k = self.devices or jax.device_count()
-            if k > jax.device_count():
+            devices = jax.devices()
+            k = self.devices or len(devices)
+            if k > len(devices):
                 raise ValueError(
-                    f"sharded backend needs {k} devices, host has "
-                    f"{jax.device_count()}"
+                    f"sharded backend needs {k} devices, host has {len(devices)}"
                 )
-            self._mesh = make_mesh_compat((1, k), (self.seed_axis, self.edge_axis))
+            self._mesh = auto_mesh(
+                (1, k), (self.seed_axis, self.edge_axis), devices=devices[:k]
+            )
         return self._mesh
 
     def _build(self, norm: NormalizedNetwork) -> Operator:
@@ -90,10 +92,9 @@ class ShardedEngine(LPEngine):
         cache = getattr(self, "_round_cache", None)
         if cache is not None and cache[0] is op:
             return cache[1], cache[2]
-        import jax.numpy as jnp
-
         from repro.parallel.lp_sharded import (
             build_sharded_round,
+            place_edge_shards,
             prepare_sharded_operator,
         )
 
@@ -107,10 +108,8 @@ class ShardedEngine(LPEngine):
             arrs = prepare_sharded_operator(
                 op.norm, cfg, mesh.shape[self.edge_axis]
             )
-            arrays = (
-                jnp.asarray(arrs.src),
-                jnp.asarray(arrs.dst),
-                jnp.asarray(arrs.w),
+            arrays = place_edge_shards(
+                mesh, self.edge_axis, (arrs.src, arrs.dst, arrs.w)
             )
         fn = build_sharded_round(
             mesh,
@@ -124,10 +123,11 @@ class ShardedEngine(LPEngine):
         return fn, arrays
 
     def round(self, op: Operator, F, Y):
-        import jax.numpy as jnp
+        from repro.parallel.lp_sharded import place_label_panel
 
         fn, arrays = self._round_fn(op)
-        k_seeds = self.mesh().shape[self.seed_axis]
+        mesh = self.mesh()
+        k_seeds = mesh.shape[self.seed_axis]
         F = np.asarray(F, np.float32)
         Y = np.asarray(Y, np.float32)
         if F.ndim == 1:
@@ -140,5 +140,9 @@ class ShardedEngine(LPEngine):
             z = np.zeros((F.shape[0], pad), np.float32)
             F = np.concatenate([F, z], axis=1)
             Y = np.concatenate([Y, z], axis=1)
-        out = fn(*arrays, jnp.asarray(F), jnp.asarray(Y))
+        out = fn(
+            *arrays,
+            place_label_panel(mesh, self.seed_axis, F),
+            place_label_panel(mesh, self.seed_axis, Y),
+        )
         return np.asarray(out, np.float64)[:, :s]

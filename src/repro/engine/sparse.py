@@ -38,7 +38,11 @@ from repro.core.network import NormalizedNetwork
 from repro.core.solver import LPConfig, SolveResult, chunk_columns
 from repro.engine import autotune
 from repro.engine.base import LPEngine, Operator, register_backend
-from repro.kernels.segment_reduce import csr_round_op, csr_round_residual_op
+from repro.kernels.segment_reduce import (
+    csr_round_op,
+    csr_round_ref,
+    csr_round_residual_op,
+)
 
 # device-side bucket: (rows, nbr, wgt) with nbr/wgt (R, width)
 Bucket = Tuple[jax.Array, jax.Array, jax.Array]
@@ -72,14 +76,9 @@ def _bucket_agg(buckets, inv_perm, F):
 
 
 def _bucket_round(buckets, inv_perm, F, base, *, beta2: float):
-    """Fused kernel round: ``β²·base + A @ F`` per bucket, node order.
-
-    ``use_kernel=True`` through the op wrapper: an opted-in kernel
-    backend must never silently fall back to the oracle on a size
-    heuristic.
-    """
+    """Fused kernel round: ``β²·base + A @ F`` per bucket, node order."""
     parts = [
-        csr_round_op(nbr, wgt, F, base[rows], c=beta2, use_kernel=True)
+        csr_round_op(nbr, wgt, F, base[rows], c=beta2)
         for rows, nbr, wgt in buckets
     ]
     return jnp.concatenate(parts, axis=0)[inv_perm]
@@ -333,7 +332,6 @@ def _plan_round(
             bn=bn,
             bs=bs,
             bd=bd,
-            use_kernel=True,
         )
         parts.append(out)
         dparts.append(dl)
@@ -513,19 +511,14 @@ def _dhlp1_plan_loop(
         off = 0
         for nbr, wgt in het_buckets:
             m = nbr.shape[0]
-            parts.append(
-                csr_round_op(
-                    nbr,
-                    wgt,
-                    Fq,
-                    src_het[off : off + m],
-                    c=beta,
-                    bn=bn,
-                    bs=bs,
-                    bd=bd,
-                    use_kernel=use_kernel,
+            base = src_het[off : off + m]
+            if use_kernel:
+                part = csr_round_op(
+                    nbr, wgt, Fq, base, c=beta, bn=bn, bs=bs, bd=bd
                 )
-            )
+            else:
+                part = csr_round_ref(nbr, wgt, Fq, base, beta)
+            parts.append(part)
             off += m
         Yp = jnp.concatenate(parts, axis=0)[reorder]
         Fn, inner_it = inner(Yp, F, active)
@@ -694,7 +687,6 @@ class SparseCSREngine(LPEngine):
         F0: Optional[np.ndarray] = None,
     ) -> SolveResult:
         cfg = self.config
-        pay: _CSRPayload = op.payload
         Y = np.asarray(Y)
         if Y.ndim == 1:
             Y = Y[:, None]
@@ -707,84 +699,14 @@ class SparseCSREngine(LPEngine):
         )
         parts: List[np.ndarray] = []
         outer, inner_tot, cols = 0, 0, []
-        beta = 1.0 - cfg.alpha
-        bn, bs, bd = pay.panels or (256, 128, 16)
         for Yc, F0c in zip(chunks, f0_chunks):
             Yd = jnp.asarray(Yc, jnp.float32)
             F0d = Yd if F0c is None else jnp.asarray(F0c, jnp.float32)
+            loop, args, kwargs = self._solve_call(op, Yd, F0d)
             if cfg.alg == "dhlp2":
-                if self.fused_superstep:
-                    buckets, perm, rank = self._fused_plan(op)
-                    F, it, ci = _dhlp2_plan_loop(
-                        buckets,
-                        perm,
-                        rank,
-                        Yd,
-                        F0d,
-                        beta2=beta * beta,
-                        sigma=cfg.sigma,
-                        max_iter=cfg.max_iter,
-                        seed_mode=cfg.resolved_seed_mode(),
-                        momentum=cfg.momentum,
-                        use_kernel=self.use_kernel,
-                        storage=cfg.storage_dtype,
-                        bn=bn,
-                        bs=bs,
-                        bd=bd,
-                    )
-                else:
-                    fused, fused_inv = self._fused_buckets(op)
-                    F, it, ci = _dhlp2_csr_loop(
-                        fused,
-                        fused_inv,
-                        Yd,
-                        F0d,
-                        beta2=beta * beta,
-                        sigma=cfg.sigma,
-                        max_iter=cfg.max_iter,
-                        seed_mode=cfg.resolved_seed_mode(),
-                        momentum=cfg.momentum,
-                        use_kernel=self.use_kernel,
-                    )
+                F, it, ci = loop(*args, **kwargs)
             else:
-                if self.fused_superstep:
-                    (hom_bk, het_bk, het_base_map, reorder, hom_perm,
-                     hom_rank) = pay.split_plan
-                    F, it, ti, ci = _dhlp1_plan_loop(
-                        hom_bk,
-                        het_bk,
-                        het_base_map,
-                        reorder,
-                        hom_perm,
-                        hom_rank,
-                        Yd,
-                        F0d,
-                        alpha=cfg.alpha,
-                        sigma=cfg.sigma,
-                        max_iter=cfg.max_iter,
-                        max_inner=cfg.max_inner,
-                        seed_mode=cfg.resolved_seed_mode(),
-                        use_kernel=self.use_kernel,
-                        storage=cfg.storage_dtype,
-                        bn=bn,
-                        bs=bs,
-                        bd=bd,
-                    )
-                else:
-                    (hb, hi), (mb, mi) = pay.split
-                    F, it, ti, ci = _dhlp1_csr_loop(
-                        hb,
-                        hi,
-                        mb,
-                        mi,
-                        Yd,
-                        F0d,
-                        alpha=cfg.alpha,
-                        sigma=cfg.sigma,
-                        max_iter=cfg.max_iter,
-                        max_inner=cfg.max_inner,
-                        seed_mode=cfg.resolved_seed_mode(),
-                    )
+                F, it, ti, ci = loop(*args, **kwargs)
                 inner_tot += int(ti)
             parts.append(np.asarray(F, np.float64))
             outer = max(outer, int(it))
@@ -796,6 +718,40 @@ class SparseCSREngine(LPEngine):
             converged=bool(outer < cfg.max_iter),
             per_column_iters=np.concatenate(cols),
         )
+
+    def _solve_call(self, op: Operator, Yd, F0d):
+        """``(jitted loop, args, static kwargs)`` of one seed chunk's solve."""
+        cfg = self.config
+        pay: _CSRPayload = op.payload
+        beta = 1.0 - cfg.alpha
+        common = dict(sigma=cfg.sigma, max_iter=cfg.max_iter,
+                      seed_mode=cfg.resolved_seed_mode())
+        bn, bs, bd = pay.panels or (256, 128, 16)
+        panels = dict(use_kernel=self.use_kernel, storage=cfg.storage_dtype,
+                      bn=bn, bs=bs, bd=bd)
+        if cfg.alg == "dhlp2":
+            common.update(beta2=beta * beta, momentum=cfg.momentum)
+            if self.fused_superstep:
+                return (_dhlp2_plan_loop, (*self._fused_plan(op), Yd, F0d),
+                        {**common, **panels})
+            return (_dhlp2_csr_loop, (*self._fused_buckets(op), Yd, F0d),
+                    {**common, "use_kernel": self.use_kernel})
+        common.update(alpha=cfg.alpha, max_inner=cfg.max_inner)
+        if self.fused_superstep:
+            return (_dhlp1_plan_loop, (*pay.split_plan, Yd, F0d),
+                    {**common, **panels})
+        (hb, hi), (mb, mi) = pay.split
+        return _dhlp1_csr_loop, (hb, hi, mb, mi, Yd, F0d), common
+
+    def lower(self, op: Operator, Y: np.ndarray):
+        """The lowered solve program for seed columns ``Y`` (one chunk).
+
+        ``lower(op, Y).compile().as_text()`` shows what the device runs —
+        e.g. whether the ``kernel`` backend's round is a Mosaic custom call.
+        """
+        Yd = jnp.asarray(Y, jnp.float32)
+        loop, args, kwargs = self._solve_call(op, Yd, Yd)
+        return loop.lower(*args, **kwargs)
 
     # -------------------------------------------------------------- rounds
     def _ensure_round_jit(self, op: Operator):

@@ -1,9 +1,9 @@
 """Shared kernel plumbing.
 
-TPU is the compile target; this container is CPU-only, so every kernel runs
-under ``interpret=True`` here (the Pallas interpreter executes the kernel
-body in Python with the same blocking semantics).  On a real TPU backend
-``interpret`` resolves to False and the same code lowers to Mosaic.
+TPU is the compile target.  On a TPU backend ``interpret`` resolves to
+False and every kernel lowers to Mosaic; on any other backend (the CPU
+test runs, ``JAX_PLATFORMS=cpu``) the Pallas interpreter executes the
+kernel body in Python with the same blocking semantics.
 """
 from __future__ import annotations
 
@@ -11,24 +11,16 @@ import functools
 
 import jax
 
+#: Precision of the LP engines' f32 matmuls.  A TPU runs a default-
+#: precision f32 matmul as one bf16 pass (about three significant digits,
+#: coarser than the σ the solvers converge to); HIGHEST keeps f32
+#: accuracy there.  A CPU computes f32 either way.
+F32_MATMUL = jax.lax.Precision.HIGHEST
+
 
 @functools.lru_cache(maxsize=1)
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def tpu_compiler_params(**kwargs):
-    """Build Mosaic compiler params across the Pallas rename.
-
-    Newer Pallas exposes ``pltpu.CompilerParams``; older releases call the
-    same dataclass ``pltpu.TPUCompilerParams``.  Resolve whichever exists.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(**kwargs)
 
 
 def round_up(x: int, mult: int) -> int:

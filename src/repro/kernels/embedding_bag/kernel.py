@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, default_interpret, tpu_compiler_params
+from repro.kernels.common import cdiv, default_interpret
 
 
 def _embed_bag_kernel(idx_ref, w_ref, tab_ref, out_ref, acc_ref, *, bv, v_steps, k_slots):
@@ -91,7 +91,7 @@ def embedding_bag(
         out_specs=pl.BlockSpec((bb, d), lambda i, v: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, d), table.dtype),
         scratch_shapes=[pltpu.VMEM((bb, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

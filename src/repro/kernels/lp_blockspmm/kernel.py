@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, default_interpret, tpu_compiler_params
+from repro.kernels.common import cdiv, default_interpret
 
 
 def _lp_round_kernel(base_ref, a_ref, f_ref, out_ref, acc_ref, *, c, k_steps):
@@ -88,7 +88,7 @@ def lp_round(
         out_specs=pl.BlockSpec((bm, bs), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, s_pad), F.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bs), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -3,6 +3,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.common import F32_MATMUL
+
 
 def lp_round_ref(
     A: jnp.ndarray,      # (N, N) fused operator (αβ·scale·H + α·M)
@@ -11,5 +13,5 @@ def lp_round_ref(
     c: float,            # β²
 ) -> jnp.ndarray:
     return c * base + jnp.matmul(
-        A, F, preferred_element_type=jnp.float32
+        A, F, preferred_element_type=jnp.float32, precision=F32_MATMUL
     ).astype(F.dtype)

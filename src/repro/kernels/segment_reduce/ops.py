@@ -1,23 +1,20 @@
-"""Public wrappers: padded-CSR aggregation with fallback to the oracle."""
+"""Public wrappers: padded-CSR kernels with kernel-clock instrumentation.
+
+Each wrapper always launches the Pallas kernel (Mosaic on TPU, the
+interpreter elsewhere); there is no size-based fallback to the oracle.
+A panel that does not fit the chip's VMEM fails to compile.  Callers that
+want the jnp lowering call the ``*_ref`` oracles directly.
+"""
 from __future__ import annotations
 
 import jax
 
-from repro.kernels.common import default_interpret
 from repro.kernels.segment_reduce.kernel import (
     csr_aggregate,
     csr_round,
     csr_round_residual,
 )
-from repro.kernels.segment_reduce.ref import (
-    csr_aggregate_ref,
-    csr_round_ref,
-    csr_round_residual_ref,
-)
 from repro.obs.profiler import kernel_clock, kernel_time
-
-# The resident F panel must fit VMEM alongside tiles: N·bs·4B ≲ 8MB.
-_MAX_RESIDENT_NODES = 16384
 
 
 def csr_aggregate_op(
@@ -28,17 +25,9 @@ def csr_aggregate_op(
     bn: int = 256,
     bs: int = 128,
     bd: int = 16,
-    use_kernel: bool | None = None,
 ) -> jax.Array:
-    n = F.shape[0]
-    if use_kernel is None:
-        use_kernel = 128 <= n <= _MAX_RESIDENT_NODES
     t0 = kernel_clock()
-    if not use_kernel:
-        return kernel_time("csr_aggregate.ref", t0, csr_aggregate_ref(nbr, wgt, F))
-    out = csr_aggregate(
-        nbr, wgt, F, bn=bn, bs=bs, bd=bd, interpret=default_interpret()
-    )
+    out = csr_aggregate(nbr, wgt, F, bn=bn, bs=bs, bd=bd)
     return kernel_time("csr_aggregate.kernel", t0, out)
 
 
@@ -52,24 +41,10 @@ def csr_round_op(
     bn: int = 256,
     bs: int = 128,
     bd: int = 16,
-    use_kernel: bool | None = None,
 ) -> jax.Array:
-    """Fused ``c·base + A_bucket @ F`` round for one blocked-CSR bucket.
-
-    Same size heuristic as :func:`csr_aggregate_op`; the engine registry's
-    ``kernel`` backend passes ``use_kernel=True`` so an opted-in config
-    never silently falls back to the oracle.
-    """
-    n = F.shape[0]
-    if use_kernel is None:
-        use_kernel = 128 <= n <= _MAX_RESIDENT_NODES
+    """Fused ``c·base + A_bucket @ F`` round for one blocked-CSR bucket."""
     t0 = kernel_clock()
-    if not use_kernel:
-        return kernel_time("csr_round.ref", t0, csr_round_ref(nbr, wgt, F, base, c))
-    out = csr_round(
-        nbr, wgt, F, base, c=c, bn=bn, bs=bs, bd=bd,
-        interpret=default_interpret(),
-    )
+    out = csr_round(nbr, wgt, F, base, c=c, bn=bn, bs=bs, bd=bd)
     return kernel_time("csr_round.kernel", t0, out)
 
 
@@ -84,24 +59,13 @@ def csr_round_residual_op(
     bn: int = 256,
     bs: int = 128,
     bd: int = 16,
-    use_kernel: bool | None = None,
 ) -> tuple:
     """Fused superstep for one bucket: round plus max-|out − prev| partial.
 
     Returns ``(out, delta)``; ``delta`` has one max-partial row per row
-    block (``(grid_m, S)`` from the kernel, ``(1, S)`` from the oracle) —
-    callers reduce with ``jnp.max(delta, axis=0)`` after concatenating
-    buckets. Same size heuristic as :func:`csr_aggregate_op`.
+    block (``(grid_m, S)``) — callers reduce with ``jnp.max(delta,
+    axis=0)`` after concatenating buckets.
     """
-    n = F.shape[0]
-    if use_kernel is None:
-        use_kernel = 128 <= n <= _MAX_RESIDENT_NODES
     t0 = kernel_clock()
-    if not use_kernel:
-        out = csr_round_residual_ref(nbr, wgt, F, base, prev, c)
-        return kernel_time("csr_round_residual.ref", t0, out)
-    out = csr_round_residual(
-        nbr, wgt, F, base, prev, c=c, bn=bn, bs=bs, bd=bd,
-        interpret=default_interpret(),
-    )
+    out = csr_round_residual(nbr, wgt, F, base, prev, c=c, bn=bn, bs=bs, bd=bd)
     return kernel_time("csr_round_residual.kernel", t0, out)

@@ -399,6 +399,8 @@ def _describe(art) -> List[str]:
             line += f"  achieved/offered={r['achieved_vs_offered']:.2f}"
         src = ", ".join(f"{s}:{n}" for s, n in sorted(r["sources"].items()))
         out = [line, f"[serve] sources: {src}"]
+        if art.failures:
+            out.append(f"[serve] FAILED: {art.failures} queries")
         if getattr(art, "ft", None):
             ft = art.ft
             out.append(

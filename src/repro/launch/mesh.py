@@ -8,18 +8,18 @@ see the single real CPU device.
 """
 from __future__ import annotations
 
-from repro.parallel.hints import make_mesh_compat
+from repro.parallel.hints import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape, axes):
     """Small mesh over whatever devices exist (tests, local runs)."""
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:

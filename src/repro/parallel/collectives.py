@@ -81,10 +81,7 @@ def ring_allreduce_ppermute(x: jax.Array, axis_name: str) -> jax.Array:
     ``collective-permute`` ops in the HLO that the compiler can interleave
     with compute issued between steps.
     """
-    # lax.axis_size is a newer-JAX addition; psum of a Python scalar
-    # constant-folds to the static axis size on older releases.
-    axis_size = getattr(lax, "axis_size", None)
-    k = axis_size(axis_name) if axis_size is not None else lax.psum(1, axis_name)
+    k = lax.axis_size(axis_name)
     if k == 1:
         return x
     perm = [(i, (i + 1) % k) for i in range(k)]

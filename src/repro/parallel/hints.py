@@ -21,7 +21,7 @@ import threading
 from typing import Optional, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axes
 BATCH = "__batch__"      # data-parallel axes: ("pod", "data") ∩ mesh
@@ -30,44 +30,15 @@ TP = "__model__"         # tensor/expert-parallel axis: "model"
 _state = threading.local()
 
 
-def auto_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` where available, else ``None``.
+def auto_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagates
+    shardings from the inputs; ``make_mesh`` defaults to ``Explicit``).
 
-    ``jax.sharding.AxisType`` only exists in newer JAX releases; on older
-    installs ``jax.make_mesh`` takes no ``axis_types`` and every axis is
-    implicitly Auto, so omitting the kwarg is the exact equivalent.
+    ``devices`` picks the devices to lay out (default: all of them).
     """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis_types across JAX versions."""
-    kwargs = {}
-    types = auto_axis_types(len(axes))
-    if types is not None:
-        kwargs["axis_types"] = types
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map`` with a ``check_vma`` kwarg; older
-    releases have ``jax.experimental.shard_map.shard_map`` with the same
-    flag named ``check_rep``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def set_ambient_mesh(mesh: Optional[Mesh]) -> None:

@@ -33,8 +33,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.hints import shard_map_compat
-
 from repro.core.blocked_csr import (
     blocked_csr_from_network,
     split_blocked_csr_from_network,
@@ -77,6 +75,22 @@ def _shard_edges(src, dst, w, num_edge_shards: int):
         dst.reshape(num_edge_shards, per),
         w.reshape(num_edge_shards, per),
     )
+
+
+def place_edge_shards(mesh: Mesh, edge_axis: str, arrays) -> Tuple[jax.Array, ...]:
+    """Upload stacked ``(k, Ep)`` edge shards one shard per device.
+
+    A plain ``jnp.asarray`` would stage every shard whole on the first
+    device; with the mesh sharding each device receives only its own.
+    """
+    sharding = NamedSharding(mesh, P(edge_axis, None))
+    return tuple(jax.device_put(a, sharding) for a in arrays)
+
+
+def place_label_panel(mesh: Mesh, seed_axis: str, x: np.ndarray) -> jax.Array:
+    """Upload an ``(N, S)`` label panel split by columns over the seed axis."""
+    sharding = NamedSharding(mesh, P(None, seed_axis))
+    return jax.device_put(np.asarray(x, np.float32), sharding)
 
 
 def prepare_sharded_operator(
@@ -190,7 +204,7 @@ def build_sharded_dhlp2(
         # iteration counts differ across seed shards; report local columns'.
         return F, jnp.reshape(iters, (1,)), col_iters
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(
@@ -201,7 +215,7 @@ def build_sharded_dhlp2(
             P(None, seed_axis),
         ),
         out_specs=(P(None, seed_axis), P(seed_axis), P(seed_axis)),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -231,7 +245,7 @@ def build_sharded_round(
         agg = compressed_psum(local, edge_axis, compression=compression)
         return beta2 * Y + agg
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(
@@ -242,7 +256,7 @@ def build_sharded_round(
             P(None, seed_axis),
         ),
         out_specs=P(None, seed_axis),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -330,7 +344,7 @@ def build_sharded_dhlp1(
         F, _, iters, tot_inner, _ = lax.while_loop(cond, body, state0)
         return F, jnp.reshape(iters, (1,)), jnp.reshape(tot_inner, (1,))
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(
@@ -340,7 +354,7 @@ def build_sharded_dhlp1(
             P(None, seed_axis),
         ),
         out_specs=(P(None, seed_axis), P(seed_axis), P(seed_axis)),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(mapped)
 
@@ -427,9 +441,7 @@ class ShardedHeteroLP:
                 seed_axis=seed_axis,
                 compression=self.compression,
             )
-            arrays = tuple(
-                jnp.asarray(a) for a in (*het, *hom)
-            )
+            arrays = place_edge_shards(mesh, edge_axis, (*het, *hom))
         else:
             arrs = prepare_sharded_operator(norm, cfg, k_edges)
             solver = build_sharded_dhlp2(
@@ -444,10 +456,8 @@ class ShardedHeteroLP:
                 stale_sync=self.stale_sync,
                 compression=self.compression,
             )
-            arrays = (
-                jnp.asarray(arrs.src),
-                jnp.asarray(arrs.dst),
-                jnp.asarray(arrs.w),
+            arrays = place_edge_shards(
+                mesh, edge_axis, (arrs.src, arrs.dst, arrs.w)
             )
         prep = ShardedPrepared(
             mesh=mesh,
@@ -487,8 +497,8 @@ class ShardedHeteroLP:
                 F0 = np.concatenate(
                     [F0, np.zeros((n, pad_s), F0.dtype)], axis=1
                 )
-        Yd = jnp.asarray(Y, jnp.float32)
-        F0d = jnp.asarray(F0, jnp.float32)
+        Yd = place_label_panel(prep.mesh, prep.seed_axis, Y)
+        F0d = place_label_panel(prep.mesh, prep.seed_axis, F0)
 
         if prep.alg == "dhlp1":
             F, iters, tot_inner = prep.solver(*prep.arrays, Yd, F0d)

@@ -52,6 +52,25 @@ def _sample(result) -> Dict:
     }
 
 
+def _answered(futures, *, timeout: float = 600.0) -> List:
+    """Results of the queries that were answered, in submission order.
+
+    A failed batch sets its exception on every co-batched future; those
+    queries are left out here and counted by the scheduler's
+    ``stats.failed``, which the report carries as ``failed``.
+    """
+    results = []
+    for fut in futures:
+        if fut.exception(timeout=timeout) is None:
+            results.append(fut.result())
+    return results
+
+
+def _latency_percentiles(lats) -> Dict:
+    """p50/p95/p99 of the answered queries; NaN when none was answered."""
+    return percentiles(lats) or dict.fromkeys(("p50", "p95", "p99"), float("nan"))
+
+
 def replay_trace(
     engine,
     trace,
@@ -103,7 +122,7 @@ def replay_trace(
         if telemetry is not None:
             telemetry.count("serve.replay.submitted")
             telemetry.maybe_flush()  # submit loop = arrival-side pump
-    results = [f.result(timeout=600) for f in futs]
+    results = _answered(futs)
     wall = time.monotonic() - t0
     engine.stop()
     lats = [r.latency_s for r in results]
@@ -113,19 +132,20 @@ def replay_trace(
     achieved = len(results) / wall
     out = {
         "queries": len(results),
+        "failed": engine.batcher.stats.failed,
         "offered_qps": offered,
         "qps": achieved,
         "achieved_vs_offered": achieved / offered if offered else 0.0,
         "wall_s": wall,
         "deltas_applied": di,
-        "mean_rounds": float(np.mean([r.rounds for r in results])),
+        "mean_rounds": float(np.mean([r.rounds for r in results])) if results else 0.0,
         "sources": {s: sources.count(s) for s in set(sources)},
         "batches": engine.batcher.stats.batches,
         "mean_batch_size": engine.batcher.stats.mean_batch_size,
         "latencies": lats,
-        "sample": _sample(results[0]),
+        "sample": _sample(results[0]) if results else {},
     }
-    out.update(percentiles(lats))
+    out.update(_latency_percentiles(lats))
     return out
 
 
@@ -144,7 +164,12 @@ def play_zipf(
 ) -> Dict:
     """Zipf-popular entities of ``source_type`` querying ``target_type``
     candidates, with ``deltas`` random associations landing online at
-    even intervals through the workload."""
+    even intervals through the workload.
+
+    The report's ``answers`` holds the answered ``QueryResult`` objects in
+    submission order (each carries the network version it was computed
+    on), for callers that check answers against a solve.
+    """
     net = engine.state.net
     rng = np.random.default_rng(seed)
     n_src = net.sizes[source_type]
@@ -190,7 +215,7 @@ def play_zipf(
         if telemetry is not None:
             telemetry.count("serve.replay.submitted")
             telemetry.maybe_flush()  # submit loop = arrival-side pump
-    results = [f.result(timeout=600) for f in futures]
+    results = _answered(futures)
     wall = time.monotonic() - t0
     engine.stop()
 
@@ -204,6 +229,7 @@ def play_zipf(
     cstats = engine.columns.stats
     out = {
         "queries": len(results),
+        "failed": bstats.failed,
         "qps": len(results) / wall,
         "wall_s": wall,
         "sources": dict(by_source),
@@ -216,7 +242,8 @@ def play_zipf(
         "cache_evictions": cstats.evictions,
         "cache_demoted": cstats.invalidations,
         "latencies": lats,
-        "sample": _sample(results[0]),
+        "answers": results,
+        "sample": _sample(results[0]) if results else {},
     }
-    out.update(percentiles(lats))
+    out.update(_latency_percentiles(lats))
     return out

@@ -92,12 +92,12 @@ class TestCheckpoint:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.parallel.hints import make_mesh_compat
+        from repro.parallel.hints import auto_mesh
 
         cm = CheckpointManager(str(tmp_path))
         t = tree(3)
         cm.save(1, t)
-        mesh = make_mesh_compat((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         sh = jax.tree_util.tree_map(
             lambda _: NamedSharding(mesh, P()), t
         )
@@ -369,13 +369,13 @@ class TestRemesh:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.ft import remesh
-        from repro.parallel.hints import make_mesh_compat
+        from repro.parallel.hints import auto_mesh
 
         cm = CheckpointManager(str(tmp_path))
         t = tree(5)
 
         def make_shardings(n):
-            mesh = make_mesh_compat((n,), ("data",))
+            mesh = auto_mesh((n,), ("data",))
             return jax.tree_util.tree_map(
                 lambda _: NamedSharding(mesh, P()), t
             )

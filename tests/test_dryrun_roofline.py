@@ -91,7 +91,8 @@ class TestRooflineAnalyzer:
 
     def test_terms_and_bottleneck(self):
         a = roofline.analyze(self._rec())
-        assert a["t_compute_s"] == pytest.approx(1e9 / roofline.PEAK_FLOPS)
+        peak = roofline.PEAKS[roofline.DRYRUN_TARGET]["flops"]
+        assert a["t_compute_s"] == pytest.approx(1e9 / peak)
         assert a["bottleneck"] in ("compute", "memory", "collective")
         assert 0 < a["compute_fraction"] <= 1.0
 
@@ -110,6 +111,23 @@ class TestRooflineAnalyzer:
         rec.pop("probe")
         a = roofline.analyze(rec)
         assert a["flops_per_device"] == pytest.approx(1e9)
+
+
+class TestAchievedVsPeak:
+    COST = {"flops": 2e9, "bytes": 4e9}
+
+    def test_known_device_priced_at_its_own_peaks(self):
+        out = roofline.achieved_vs_peak(1e-3, self.COST, "TPU v5 lite")
+        peak = roofline.PEAKS["TPU v5 lite"]
+        assert out["achieved_gflops"] == pytest.approx(2e3)
+        assert out["frac_peak_flops"] == pytest.approx(2e12 / peak["flops"])
+        assert out["frac_peak_bw"] == pytest.approx(4e12 / peak["hbm_bw"])
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "NVIDIA H100"])
+    def test_unknown_device_gets_no_peak_share(self, kind):
+        out = roofline.achieved_vs_peak(1e-3, self.COST, kind)
+        assert out["achieved_gbps"] == pytest.approx(4e3)
+        assert not any(k.startswith("frac_peak") for k in out)
 
 
 class TestShippedArtifacts:

@@ -22,11 +22,11 @@ sys.path.insert(0, %(src)r)
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
-from repro.parallel.hints import make_mesh_compat
+from repro.parallel.hints import auto_mesh
 
 cm = CheckpointManager(%(root)r)
 like = {"w": jnp.zeros((64, 32)), "b": jnp.zeros((32,))}
-mesh = make_mesh_compat((8,), ("data",))
+mesh = auto_mesh((8,), ("data",))
 sh = {"w": NamedSharding(mesh, P("data", None)),
       "b": NamedSharding(mesh, P())}
 step, restored = cm.restore_latest(like, shardings=sh)
@@ -47,19 +47,18 @@ sys.path.insert(0, %(src)r)
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import grad_allreduce
-from repro.parallel.hints import make_mesh_compat
+from repro.parallel.hints import auto_mesh
 
-mesh = make_mesh_compat((4,), ("d",))
+mesh = auto_mesh((4,), ("d",))
 rng = np.random.default_rng(0)
 g = rng.standard_normal((16, 8)).astype(np.float32)
 
 def body(gs, key):
     return grad_allreduce({"g": gs}, "d", compression="int8", key=key)["g"]
 
-from repro.parallel.hints import shard_map_compat
-f = jax.jit(shard_map_compat(body, mesh=mesh,
+f = jax.jit(jax.shard_map(body, mesh=mesh,
                              in_specs=(P("d", None), P()),
-                             out_specs=P("d", None), check=False))
+                             out_specs=P("d", None), check_vma=False))
 out = np.asarray(f(g, jax.random.PRNGKey(0)))
 # exact per-shard sums for comparison
 want = g.reshape(4, 4, 8).sum(axis=0)

@@ -119,6 +119,41 @@ class TestServeKillReplay:
         assert serve.report["queries"] > 0
 
 
+class TestServeFailureExit:
+    # a fault on the first attempt, no retry budget, and a second fault on
+    # the restore's replay: the batch fails and its queries go unanswered
+    _FT = {"max_retries": 0, "inject_serve_fault": [1, 2]}
+
+    def test_failed_batch_counted_in_artifact(self, tmp_path):
+        spec = _spec("servefail", **self._FT)
+        arts = Session(spec, results_root=str(tmp_path)).run(echo=_quiet)
+        serve = next(a for a in arts if a.kind == "serve")
+        assert serve.failures > 0
+        assert serve.failures == serve.report["failed"]
+        assert serve.summary()["failures"] == serve.failures
+        assert serve.report["queries"] > 0  # the other batches answered
+
+    def test_failed_serve_queries_make_repro_run_exit_1(self, tmp_path, capsys):
+        import json
+
+        from repro.launch.cli import run_main
+
+        path = tmp_path / "spec.json"
+        path.write_text(json.dumps(_spec("servefailcli", **self._FT).to_dict()))
+        rc = run_main([str(path), "--results-root", str(tmp_path)])
+        assert rc == 1
+        assert "[serve] FAILED:" in capsys.readouterr().out
+
+    def test_clean_serve_exits_0(self, tmp_path):
+        import json
+
+        from repro.launch.cli import run_main
+
+        path = tmp_path / "spec.json"
+        path.write_text(json.dumps(_spec("servecleancli").to_dict()))
+        assert run_main([str(path), "--results-root", str(tmp_path)]) == 0
+
+
 class TestTelemetryRollup:
     def test_digest_carries_ft_block(self, tmp_path):
         from repro.obs.summary import load_dir, render, summarize

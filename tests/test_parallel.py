@@ -18,7 +18,7 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import HeteroNetwork, HeteroLP, LPConfig
 from repro.parallel.lp_sharded import ShardedHeteroLP
-from repro.parallel.hints import make_mesh_compat
+from repro.parallel.hints import auto_mesh
 from repro.parallel.collectives import (
     compressed_psum, psum_scatter_then_gather, ring_allreduce_ppermute,
 )
@@ -33,7 +33,7 @@ R = {(i, j): (rng.random((n[i], n[j])) < 0.3).astype(float)
      for (i, j) in [(0, 1), (0, 2), (1, 2)]}
 net = HeteroNetwork(P=Pm, R=R)
 norm = net.normalize()
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 cfg = LPConfig(alg="dhlp2", seed_mode="fixed", sigma=1e-6, max_iter=3000)
 dense = HeteroLP(cfg).run(net)
 out = {}
@@ -62,10 +62,9 @@ def body(xs):
         psum_scatter_then_gather(xs, "d"),
         ring_allreduce_ppermute(xs, "d"),
     )
-m1 = make_mesh_compat((8,), ("d",))
-from repro.parallel.hints import shard_map_compat
-f = jax.jit(shard_map_compat(body, mesh=m1, in_specs=P("d", None),
-                             out_specs=(P("d", None),) * 3, check=False))
+m1 = auto_mesh((8,), ("d",))
+f = jax.jit(jax.shard_map(body, mesh=m1, in_specs=P("d", None),
+                             out_specs=(P("d", None),) * 3, check_vma=False))
 a, b, c = f(x)
 out["psum_ok"] = bool(np.allclose(np.asarray(a), np.asarray(b)) and
                       np.allclose(np.asarray(a), np.asarray(c)))
@@ -120,10 +119,10 @@ class TestHints:
         import jax
         import jax.numpy as jnp
         from repro.parallel.hints import (
-            BATCH, make_mesh_compat, shard_hint, set_ambient_mesh,
+            BATCH, auto_mesh, shard_hint, set_ambient_mesh,
         )
 
-        mesh = make_mesh_compat((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         set_ambient_mesh(mesh)
         try:
             x = jnp.ones((4, 8))
@@ -136,10 +135,10 @@ class TestHints:
         import jax
         import jax.numpy as jnp
         from repro.parallel.hints import (
-            make_mesh_compat, shard_hint, set_ambient_mesh,
+            auto_mesh, shard_hint, set_ambient_mesh,
         )
 
-        mesh = make_mesh_compat((1,), ("data",))
+        mesh = auto_mesh((1,), ("data",))
         set_ambient_mesh(mesh)
         try:
             with pytest.raises(ValueError):
