@@ -189,6 +189,7 @@ class Session:
             self._engine = make_engine(
                 self.backend, self.lp_config(), **self._engine_kwargs()
             )
+            self._engine.telemetry = self.telemetry
         return self._engine
 
     @property
@@ -200,6 +201,7 @@ class Session:
             self._eval_engine = make_engine(
                 self.backend, self.lp_config(), **self._engine_kwargs()
             )
+            self._eval_engine.telemetry = self.telemetry
         return self._eval_engine
 
     @property
@@ -713,31 +715,21 @@ class Session:
             # live mode: telemetry/<run_id> becomes readable mid-run and
             # the SLO watchdog (if any) gets its per-window flush ticks
             tel.attach_stream(tel_dir, interval_s=obs.flush_interval_s)
-        if tel.profile_enabled:
-            from repro.obs.profiler import install_kernel_hook
-
-            install_kernel_hook(tel)
         artifacts: List[Artifact] = []
-        try:
-            with tel.span("run", self.run_id, sections=list(names)):
-                for name in names:
-                    with tel.span("phase", name):
-                        if name in ("solve", "serve") and tel.profile_enabled:
-                            from repro.obs.profiler import profile_phase
+        with tel.span("run", self.run_id, sections=list(names)):
+            for name in names:
+                with tel.span("phase", name):
+                    if name in ("solve", "serve") and tel.profile_enabled:
+                        from repro.obs.profiler import profile_phase
 
-                            with profile_phase(tel, tel_dir, name):
-                                art = stages[name]()
-                        else:
+                        with profile_phase(tel, tel_dir, name):
                             art = stages[name]()
-                    artifacts.append(art)
-                    if write:
-                        for path in art.write(self.run_dir):
-                            echo(f"[{name}] wrote {path}")
-        finally:
-            if tel.profile_enabled:
-                from repro.obs.profiler import uninstall_kernel_hook
-
-                uninstall_kernel_hook()
+                    else:
+                        art = stages[name]()
+                artifacts.append(art)
+                if write:
+                    for path in art.write(self.run_dir):
+                        echo(f"[{name}] wrote {path}")
         if write and tel.enabled:
             for path in tel.flush(tel_dir):
                 echo(f"[obs] wrote {path}")

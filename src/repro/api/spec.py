@@ -489,8 +489,9 @@ class ObsSpec:
     """Telemetry level + live-streaming knobs for the run (DESIGN.md §14).
 
     ``metrics`` records counters/gauges/histograms + structural spans;
-    ``trace`` adds per-superstep and per-query spans; ``profile`` adds
-    the ``jax.profiler`` capture and kernel timing hooks.  Writing the
+    ``trace`` adds per-superstep, per-query and engine/serve step spans;
+    ``profile`` adds the ``jax.profiler`` capture of the solve/serve
+    phases, which the recorded spans land in.  Writing the
     section at all defaults to ``metrics`` — an explicit ``off`` keeps
     the spec round-trippable while disabling collection.
 

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.core.network import NormalizedNetwork, seeds_identity
 from repro.core.solver import LPConfig, SolveResult, coerce_normalized
+from repro.obs.telemetry import trace_span
 
 # `auto` picks dense while the (N, N) fused operator stays comfortably
 # in device memory (4096² f32 = 64 MB) AND the network is dense enough
@@ -67,6 +68,9 @@ class LPEngine(abc.ABC):
 
     def __init__(self, config: LPConfig = LPConfig()):
         self.config = config
+        #: the owning Session's Telemetry (None: spans are null); read by
+        #: host code around the jitted loops, never passed into them
+        self.telemetry = None
         # (norm, Operator): identity-keyed like the solvers' caches — the
         # entry holds the norm object itself so a recycled id() cannot
         # alias a different network.
@@ -96,8 +100,8 @@ class LPEngine(abc.ABC):
                 f"backend {self.name!r} has no momentum loop "
                 f"(LPConfig.momentum={self.config.momentum})"
             )
-        norm = coerce_normalized(net)
-        op = self._build(norm)
+        with trace_span(self.telemetry, "engine.prepare"):
+            op = self._build(coerce_normalized(net))
         self._op_cache = (net, op)
         return op
 

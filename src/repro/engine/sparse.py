@@ -43,6 +43,7 @@ from repro.kernels.segment_reduce import (
     csr_round_ref,
     csr_round_residual_op,
 )
+from repro.obs.telemetry import trace_span
 
 # device-side bucket: (rows, nbr, wgt) with nbr/wgt (R, width)
 Bucket = Tuple[jax.Array, jax.Array, jax.Array]
@@ -263,18 +264,18 @@ def _tighten_buckets(buckets):
     return out
 
 
-def _device_plan(bcsr, *, storage: str, weight_scale: float = 1.0):
+def _device_plan(tight, *, storage: str, weight_scale: float = 1.0):
     """Permuted-space bucket plan for the fused-superstep loops.
 
+    ``tight`` is a blocked-CSR operator's buckets re-bucketed by exact
+    width (:func:`_tighten_buckets`) — the plan's main perf lever.
     Returns ``(buckets, perm, rank)``: ``perm`` is the bucket-concat row
     order (node id at each permuted position), ``rank = argsort(perm)``
     (permuted position of each node id).  Bucket neighbor ids are
     pre-remapped through ``rank`` so rounds gather from — and write to —
     permuted space directly: output rows land contiguously at static
-    offsets, no per-round inverse permute.  Rows are re-bucketed by
-    exact width (:func:`_tighten_buckets`) — the plan's main perf lever.
+    offsets, no per-round inverse permute.
     """
-    tight = _tighten_buckets(bcsr.width_buckets())
     order = np.concatenate([rows for rows, _, _ in tight])
     rank = np.argsort(order).astype(np.int32)
     wdt = _STORAGE[storage]
@@ -614,7 +615,9 @@ class SparseCSREngine(LPEngine):
             )
             if self.fused_superstep:
                 hom_bk, hom_perm, hom_rank = _device_plan(
-                    hom, storage=cfg.storage_dtype, weight_scale=cfg.alpha
+                    _tighten_buckets(hom.width_buckets()),
+                    storage=cfg.storage_dtype,
+                    weight_scale=cfg.alpha,
                 )
                 het_buckets = het.width_buckets()
                 het_order = np.concatenate([b.rows for b in het_buckets])
@@ -672,12 +675,21 @@ class SparseCSREngine(LPEngine):
         return pay.fused, pay.fused_inv
 
     def _fused_plan(self, op: Operator):
-        """Permuted-space fused plan, built on first use."""
+        """Permuted-space fused plan, built on first use.
+
+        Spans: ``engine.prepare.csr`` the host assembly (blocked CSR and
+        exact-width buckets), ``engine.prepare.upload`` the device plan
+        until its arrays are ready — waited for only when recorded.
+        """
         pay: _CSRPayload = op.payload
         if pay.plan is None:
-            pay.plan = _device_plan(
-                self._fused_bcsr(op), storage=self.config.storage_dtype
-            )
+            tel = self.telemetry
+            with trace_span(tel, "engine.prepare.csr"):
+                tight = _tighten_buckets(self._fused_bcsr(op).width_buckets())
+            with trace_span(tel, "engine.prepare.upload") as span:
+                pay.plan = _device_plan(tight, storage=self.config.storage_dtype)
+                if span.id is not None:
+                    jax.block_until_ready(pay.plan)
         return pay.plan
 
     def solve(
@@ -699,18 +711,27 @@ class SparseCSREngine(LPEngine):
         )
         parts: List[np.ndarray] = []
         outer, inner_tot, cols = 0, 0, []
+        tel = self.telemetry
+        # per chunk: engine.upload (seeds to the device), engine.loop
+        # (dispatch until F is ready), engine.fetch (device->host copy and
+        # float64 widening); the waits happen only in recorded spans
         for Yc, F0c in zip(chunks, f0_chunks):
-            Yd = jnp.asarray(Yc, jnp.float32)
-            F0d = Yd if F0c is None else jnp.asarray(F0c, jnp.float32)
-            loop, args, kwargs = self._solve_call(op, Yd, F0d)
-            if cfg.alg == "dhlp2":
-                F, it, ci = loop(*args, **kwargs)
-            else:
-                F, it, ti, ci = loop(*args, **kwargs)
-                inner_tot += int(ti)
-            parts.append(np.asarray(F, np.float64))
-            outer = max(outer, int(it))
-            cols.append(np.asarray(ci))
+            with trace_span(tel, "engine.upload") as span:
+                Yd = jnp.asarray(Yc, jnp.float32)
+                F0d = Yd if F0c is None else jnp.asarray(F0c, jnp.float32)
+                if span.id is not None:
+                    jax.block_until_ready((Yd, F0d))
+            with trace_span(tel, "engine.loop") as span:
+                loop, args, kwargs = self._solve_call(op, Yd, F0d)
+                out = loop(*args, **kwargs)
+                if span.id is not None:
+                    jax.block_until_ready(out)
+            with trace_span(tel, "engine.fetch"):
+                parts.append(np.asarray(out[0], np.float64))
+                outer = max(outer, int(out[1]))
+                cols.append(np.asarray(out[-1]))
+                if cfg.alg == "dhlp1":
+                    inner_tot += int(out[2])
         return SolveResult(
             F=np.concatenate(parts, axis=1),
             outer_iters=outer,

@@ -6,7 +6,6 @@ import jax
 from repro.kernels.common import default_interpret
 from repro.kernels.embedding_bag.kernel import embedding_bag
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
-from repro.obs.profiler import kernel_clock, kernel_time
 
 
 def embedding_bag_op(
@@ -20,10 +19,6 @@ def embedding_bag_op(
 ) -> jax.Array:
     if use_kernel is None:
         use_kernel = idx.shape[0] >= 128
-    t0 = kernel_clock()
     if not use_kernel:
-        return kernel_time("embedding_bag.ref", t0, embedding_bag_ref(table, idx, w))
-    out = embedding_bag(
-        table, idx, w, bb=bb, bv=bv, interpret=default_interpret()
-    )
-    return kernel_time("embedding_bag.kernel", t0, out)
+        return embedding_bag_ref(table, idx, w)
+    return embedding_bag(table, idx, w, bb=bb, bv=bv, interpret=default_interpret())

@@ -10,7 +10,6 @@ import jax
 from repro.kernels.common import default_interpret
 from repro.kernels.lp_blockspmm.kernel import lp_round
 from repro.kernels.lp_blockspmm.ref import lp_round_ref
-from repro.obs.profiler import kernel_clock, kernel_time
 
 _MIN_DIM_FOR_KERNEL = 128
 
@@ -29,10 +28,6 @@ def lp_round_op(
     n, s = F.shape
     if use_kernel is None:
         use_kernel = n >= _MIN_DIM_FOR_KERNEL and s >= _MIN_DIM_FOR_KERNEL
-    t0 = kernel_clock()
     if not use_kernel:
-        return kernel_time("lp_round.ref", t0, lp_round_ref(A, F, base, c))
-    out = lp_round(
-        A, F, base, c=c, bm=bm, bs=bs, bk=bk, interpret=default_interpret()
-    )
-    return kernel_time("lp_round.kernel", t0, out)
+        return lp_round_ref(A, F, base, c)
+    return lp_round(A, F, base, c=c, bm=bm, bs=bs, bk=bk, interpret=default_interpret())

@@ -1,4 +1,4 @@
-"""Public wrappers: padded-CSR kernels with kernel-clock instrumentation.
+"""Public wrappers of the padded-CSR kernels.
 
 Each wrapper always launches the Pallas kernel (Mosaic on TPU, the
 interpreter elsewhere); there is no size-based fallback to the oracle.
@@ -14,7 +14,6 @@ from repro.kernels.segment_reduce.kernel import (
     csr_round,
     csr_round_residual,
 )
-from repro.obs.profiler import kernel_clock, kernel_time
 
 
 def csr_aggregate_op(
@@ -26,9 +25,7 @@ def csr_aggregate_op(
     bs: int = 128,
     bd: int = 16,
 ) -> jax.Array:
-    t0 = kernel_clock()
-    out = csr_aggregate(nbr, wgt, F, bn=bn, bs=bs, bd=bd)
-    return kernel_time("csr_aggregate.kernel", t0, out)
+    return csr_aggregate(nbr, wgt, F, bn=bn, bs=bs, bd=bd)
 
 
 def csr_round_op(
@@ -43,9 +40,7 @@ def csr_round_op(
     bd: int = 16,
 ) -> jax.Array:
     """Fused ``c·base + A_bucket @ F`` round for one blocked-CSR bucket."""
-    t0 = kernel_clock()
-    out = csr_round(nbr, wgt, F, base, c=c, bn=bn, bs=bs, bd=bd)
-    return kernel_time("csr_round.kernel", t0, out)
+    return csr_round(nbr, wgt, F, base, c=c, bn=bn, bs=bs, bd=bd)
 
 
 def csr_round_residual_op(
@@ -66,6 +61,4 @@ def csr_round_residual_op(
     block (``(grid_m, S)``) — callers reduce with ``jnp.max(delta,
     axis=0)`` after concatenating buckets.
     """
-    t0 = kernel_clock()
-    out = csr_round_residual(nbr, wgt, F, base, prev, c=c, bn=bn, bs=bs, bd=bd)
-    return kernel_time("csr_round_residual.kernel", t0, out)
+    return csr_round_residual(nbr, wgt, F, base, prev, c=c, bn=bn, bs=bs, bd=bd)

@@ -1,16 +1,17 @@
-"""Observability subsystem: telemetry, metrics, profiler hooks (DESIGN.md §14).
+"""Observability subsystem: telemetry, metrics, profiler phases (DESIGN.md §14).
 
 The cross-cutting layer a :class:`~repro.api.session.Session` threads
 through solve/serve/bench/dryrun when the spec carries an ``obs``
 section:
 
 * :mod:`repro.obs.telemetry` — structured spans/events + the level gate;
+  recorded spans also land in a ``jax.profiler`` trace as ``repro.<kind>``;
 * :mod:`repro.obs.metrics`   — counters, gauges, log-bucket histograms;
 * :mod:`repro.obs.schema`    — JSONL schema validation (CI + ``--validate``);
 * :mod:`repro.obs.export`    — OpenMetrics text snapshots (render/parse/lint);
 * :mod:`repro.obs.slo`       — SLO watchdog + serve degradation ladder;
 * :mod:`repro.obs.solve`     — the observed per-superstep solve loop;
-* :mod:`repro.obs.profiler`  — ``jax.profiler`` phases + kernel timing;
+* :mod:`repro.obs.profiler`  — ``jax.profiler`` phase traces;
 * :mod:`repro.obs.summary`   — digest + text rendering for ``repro obs``.
 
 Import-light on purpose: importing :mod:`repro.obs` must not pull jax

@@ -1,67 +1,17 @@
-"""Profiler hooks: ``jax.profiler`` traces + kernel timing (DESIGN.md §14.4).
+"""Profiler phases: ``jax.profiler`` traces of Session phases (DESIGN.md §14.4).
 
-Two opt-in capture paths, both active only at ``obs.level="profile"``:
-
-* :func:`profile_phase` — a ``jax.profiler.trace`` context the Session
-  wraps around its solve/serve phases, writing the device trace under
-  ``results/<run_id>/telemetry/profile/``;
-* the kernel hook — :func:`kernel_clock` / :func:`kernel_time` pairs in
-  the ``kernels/`` op wrappers.  Per-variant wall times land in
-  ``kernel.<name>.latency_s`` histograms so the roofline suite can
-  attribute achieved FLOPs/bandwidth to named kernels.
-
-The kernel hook is a module global, not a parameter: op wrappers are
-called from deep inside engine loops where threading a telemetry handle
-through every signature would contaminate jit static args.  When no
-collector is installed, the cost per op call is one global load + one
-``is None`` branch.  Calls made during jit *tracing* return a
-``jax.core.Tracer`` — those are skipped (a trace-time wall clock times
-program construction, not the kernel), so only eager invocations (e.g.
-``engine.round`` refresh paths) are measured, with ``block_until_ready``
-making the timing honest about async dispatch.
+:func:`profile_phase` is a ``jax.profiler.trace`` context the Session
+wraps around its solve/serve phases at ``obs.level="profile"``, writing
+the device trace under ``results/<run_id>/telemetry/profile/``.  The
+run's spans reach that trace through :class:`~repro.obs.Telemetry`
+itself: every recorded span opens a ``repro.<kind>`` host annotation, on
+the clock of the device's operations.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
-import time
-from typing import Optional
-
-_COLLECTOR = None
-
-
-def install_kernel_hook(telemetry) -> None:
-    """Route kernel timings into ``telemetry`` (one collector at a time)."""
-    global _COLLECTOR
-    _COLLECTOR = telemetry
-
-
-def uninstall_kernel_hook() -> None:
-    global _COLLECTOR
-    _COLLECTOR = None
-
-
-def kernel_clock() -> Optional[float]:
-    """Timestamp for a kernel-op call; None when no collector is active."""
-    if _COLLECTOR is None:
-        return None
-    return time.perf_counter()
-
-
-def kernel_time(name: str, t0: Optional[float], out):
-    """Record one kernel-op wall time; returns ``out`` unchanged."""
-    tel = _COLLECTOR
-    if tel is None or t0 is None:
-        return out
-    import jax
-
-    if isinstance(out, jax.core.Tracer):
-        return out
-    jax.block_until_ready(out)
-    tel.observe(f"kernel.{name}.latency_s", time.perf_counter() - t0)
-    tel.count(f"kernel.{name}.calls")
-    return out
 
 
 @contextlib.contextmanager

@@ -17,11 +17,15 @@ constraints, in priority order:
   ``run``/``phase`` span, so background-thread batches nest under the
   serve phase;
 * **deterministic ids** — one process-wide increment under a lock; the
-  clock is injectable so tests assert exact timings.
+  clock is injectable so tests assert exact timings;
+* **one clock with the device** — every recorded span also opens
+  ``jax.profiler.TraceAnnotation("repro.<kind>")``, so a profiler trace
+  taken around the run holds the span beside the device's ``XLA Ops``,
+  on the same clock (the JSONL record keeps the injectable clock).
 
 Levels: ``off`` < ``metrics`` (counters/gauges/histograms + structural
-spans) < ``trace`` (adds per-superstep / per-query spans) < ``profile``
-(adds ``jax.profiler`` + kernel timing hooks, see
+spans) < ``trace`` (adds per-superstep / per-query / engine and serve
+step spans) < ``profile`` (adds ``jax.profiler`` phase traces, see
 :mod:`repro.obs.profiler`).
 
 **Streaming** (DESIGN.md §14.7): :meth:`Telemetry.attach_stream` turns
@@ -79,6 +83,22 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def trace_span(telemetry: Optional["Telemetry"], kind: str, name=None, **attrs):
+    """``telemetry.trace_span(...)``; the null span when ``telemetry`` is
+    None (components built without a Session)."""
+    if telemetry is None:
+        return _NULL_SPAN
+    return telemetry.trace_span(kind, name, **attrs)
+
+
+def _profiler_annotation(kind: str):
+    """The span's host annotation in the profiler's trace (jax is
+    imported on the first recorded span, not with this module)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(f"repro.{kind}")
+
+
 def _atomic_write(path: str, text: str) -> str:
     """Write ``text`` to ``path`` via temp-file + rename (snapshot
     rotation: a concurrent ``--follow`` reader never sees a torn file)."""
@@ -131,7 +151,17 @@ class _StreamSink:
 class Span:
     """One timed, parented region; records itself on ``__exit__``."""
 
-    __slots__ = ("_tel", "id", "parent", "kind", "name", "attrs", "t0", "_prev")
+    __slots__ = (
+        "_tel",
+        "id",
+        "parent",
+        "kind",
+        "name",
+        "attrs",
+        "t0",
+        "_prev",
+        "_ann",
+    )
 
     def __init__(
         self,
@@ -150,6 +180,7 @@ class Span:
         self.attrs = attrs
         self.t0: Optional[float] = None
         self._prev: Optional[int] = None
+        self._ann = _profiler_annotation(kind)
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -160,12 +191,14 @@ class Span:
         if self.kind in _AMBIENT_KINDS:
             self._prev = tel._ambient
             tel._ambient = self.id
+        self._ann.__enter__()
         self.t0 = tel.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         tel = self._tel
         t1 = tel.clock()
+        self._ann.__exit__(exc_type, exc, tb)
         stack = tel._stack()
         if stack and stack[-1] == self.id:
             stack.pop()

@@ -32,6 +32,7 @@ state, which is exactly the property warm-starting relies on.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -44,6 +45,7 @@ from repro.core.network import GraphDelta, HeteroNetwork
 from repro.core.ranking import topk_exclusive
 from repro.core.solver import LPConfig, SolveResult
 from repro.engine import make_engine, resolve_backend
+from repro.obs.telemetry import trace_span
 from repro.serve.cache import NetworkState, ShardedColumnCache
 from repro.serve.scheduler import MicroBatcher
 from repro.serve.types import QueryResult, QuerySpec
@@ -240,6 +242,7 @@ class LPServeEngine:
             self._engine = engine
         else:
             self._engine = make_engine(backend, config.lp)
+            self._engine.telemetry = telemetry
         self.columns = ShardedColumnCache(
             config.cache_columns,
             shards=config.cache_shards,
@@ -406,7 +409,7 @@ class LPServeEngine:
 
     def _execute_batch_impl(self, prepared: PreparedBatch) -> List[QueryResult]:
         """Batched solve + cache write-back + ranking (engine lock held)."""
-        with self._lock:
+        with self._locked("serve.lock_wait"):
             state = prepared.state
             cols, sources, rounds = (
                 prepared.cols, prepared.sources, prepared.rounds,
@@ -434,16 +437,29 @@ class LPServeEngine:
                         self.columns.put_stale(node, col)
                     else:
                         self.columns.put(state.version, node, col)
-            return [
-                self._rank(spec, cols[spec.entity], sources[spec.entity],
-                           rounds[spec.entity], state)
-                for spec in prepared.specs
-            ]
+            with trace_span(self._tel, "serve.rank"):
+                return [
+                    self._rank(spec, cols[spec.entity], sources[spec.entity],
+                               rounds[spec.entity], state)
+                    for spec in prepared.specs
+                ]
+
+    @contextlib.contextmanager
+    def _locked(self, wait_span: str):
+        """The engine lock, its wait recorded as the ``wait_span`` span."""
+        with trace_span(self._tel, wait_span):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     # ------------------------------------------------------------- the tick
     def _solve_batch(self, specs: Sequence[QuerySpec]) -> List[QueryResult]:
         """One-stage tick: the synchronous drivers' (and tests') path."""
-        return self._execute_batch(self._assemble_batch(specs))
+        with trace_span(self._tel, "serve.assemble"):
+            prepared = self._assemble_batch(specs)
+        return self._execute_batch(prepared)
 
     # ------------------------------------------------------- fault tolerance
     def enable_ft(
@@ -616,20 +632,23 @@ class LPServeEngine:
         active = np.arange(k)
         it = 0
         while active.size and it < cfg.max_iter:
-            a = int(active.size)
-            width = 1 << (a - 1).bit_length()  # next power of two
-            Fa = np.zeros((n, width), dtype=np.float64)
-            Ya = np.zeros((n, width), dtype=np.float64)
-            Fa[:, :a] = F[:, active]
-            Ya[:, :a] = Y[:, active]
-            # fused superstep: the engine emits the per-column residual
-            # from the same launch as the round (no host-side reduction)
-            Fn, delta = self._engine.round_with_residual(op, Fa, Ya)
-            Fn = np.asarray(Fn, dtype=np.float64)[:, :a]
-            delta = np.asarray(delta, dtype=np.float64)[:a]
-            F[:, active] = Fn
-            col_iters[active] += 1
-            active = active[delta >= cfg.sigma * self._sigma_scale]
+            # serve.round: host pack, the device round and its residual
+            # back, the active-set update
+            with trace_span(self._tel, "serve.round"):
+                a = int(active.size)
+                width = 1 << (a - 1).bit_length()  # next power of two
+                Fa = np.zeros((n, width), dtype=np.float64)
+                Ya = np.zeros((n, width), dtype=np.float64)
+                Fa[:, :a] = F[:, active]
+                Ya[:, :a] = Y[:, active]
+                # fused superstep: the engine emits the per-column residual
+                # from the same launch as the round (no host-side reduction)
+                Fn, delta = self._engine.round_with_residual(op, Fa, Ya)
+                Fn = np.asarray(Fn, dtype=np.float64)[:, :a]
+                delta = np.asarray(delta, dtype=np.float64)[:a]
+                F[:, active] = Fn
+                col_iters[active] += 1
+                active = active[delta >= cfg.sigma * self._sigma_scale]
             it += 1
         return SolveResult(
             F=F,
@@ -716,23 +735,26 @@ class LPServeEngine:
         adds nodes every column demotes (the id space changed shape) and
         stale hints are remapped into the new layout.
         """
-        with self._lock:
+        tel = self._tel
+        with self._locked("serve.delta.lock_wait"):
             if delta.is_empty:
                 return self._state.version
             old = self._state
-            new_net = old.net.apply_delta(delta)
-            new = NetworkState.from_network(new_net, old.version + 1)
+            with trace_span(tel, "serve.delta.normalize"):
+                new_net = old.net.apply_delta(delta)
+                new = NetworkState.from_network(new_net, old.version + 1)
             remap = None
             if delta.add_nodes:
                 remap = _make_remap(old, new)
-            self.columns.invalidate_for_delta(
-                old.version,
-                new.version,
-                delta.touched_types(),
-                old.type_of,
-                remap=remap,
-                carry_untouched=self.config.carry_untouched,
-            )
+            with trace_span(tel, "serve.delta.invalidate"):
+                self.columns.invalidate_for_delta(
+                    old.version,
+                    new.version,
+                    delta.touched_types(),
+                    old.type_of,
+                    remap=remap,
+                    carry_untouched=self.config.carry_untouched,
+                )
             self._state = new
             self._maybe_rescale_engine()
             if self.config.refresh_rounds:

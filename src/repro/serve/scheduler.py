@@ -37,8 +37,6 @@ Session's *ambient* phase span, not a stack frame of this thread.
 """
 from __future__ import annotations
 
-import contextlib
-
 import dataclasses
 import queue
 import threading
@@ -54,6 +52,7 @@ from typing import (
     Tuple,
 )
 
+from repro.obs.telemetry import trace_span
 from repro.serve.types import (
     DEFAULT_PRIORITY,
     PRIORITY_CLASSES,
@@ -374,10 +373,15 @@ class MicroBatcher:
             for c, n in snapshot.items():
                 tel.gauge(f"serve.inflight.{c}", n)
 
-    def _complete(self, live: List[_Entry], results: List[QueryResult]) -> None:
+    def _complete(
+        self, live: List[_Entry], results: List[QueryResult], t_exec: float
+    ) -> None:
+        """Resolve the batch's futures; ``t_exec`` is when it entered the
+        execute stage (each result's ``queued_s`` ends there)."""
         now = time.monotonic()
         tel = self._tel
         for (spec, fut, t_in), res in zip(live, results):
+            res.queued_s = t_exec - t_in
             res.latency_s = now - t_in
             fut.set_result(res)
             if tel is not None:
@@ -432,13 +436,9 @@ class MicroBatcher:
         if not live:
             return 0
         specs = [s for s, _, _ in live]
-        tel = self._tel
         self._record_tick(live)
-        if tel is None:
-            span = contextlib.nullcontext()
-        else:
-            span = tel.trace_span("batch", f"batch:{self.stats.batches}")
-        with span:
+        with trace_span(self._tel, "batch", f"batch:{self.stats.batches}"):
+            t_exec = time.monotonic()
             try:
                 results = self._run_guarded(self._solve_batch, specs)
                 if len(results) != len(specs):
@@ -449,7 +449,7 @@ class MicroBatcher:
             except Exception as exc:  # noqa: BLE001 — propagate to every waiter
                 self._fail(live, exc)
                 return 0
-            self._complete(live, results)
+            self._complete(live, results, t_exec)
         return len(live)
 
     def drain(self) -> int:
@@ -508,7 +508,8 @@ class MicroBatcher:
             specs = [s for s, _, _ in live]
             self._record_tick(live)
             try:
-                prepared = self._assemble(specs)
+                with trace_span(self._tel, "serve.assemble"):
+                    prepared = self._assemble(specs)
             except Exception as exc:  # noqa: BLE001 — fail this batch only
                 self._fail(live, exc)
                 continue
@@ -526,11 +527,8 @@ class MicroBatcher:
             item = self._inflight.get()
             if item is _SENTINEL:
                 return
-            if tel is None:
-                span = contextlib.nullcontext()
-            else:
-                span = tel.trace_span("batch", f"batch:{self.stats.batches}")
-            with span:
+            t_exec = time.monotonic()
+            with trace_span(tel, "batch", f"batch:{self.stats.batches}"):
                 try:
                     results = self._run_guarded(self._execute, item.prepared)
                     if len(results) != len(item.live):
@@ -541,7 +539,7 @@ class MicroBatcher:
                 except Exception as exc:  # noqa: BLE001
                     self._fail(item.live, exc)
                 else:
-                    self._complete(item.live, results)
+                    self._complete(item.live, results, t_exec)
             self._track_inflight(item.live, -1)
 
     def stop(self, timeout: float = 5.0) -> None:

@@ -44,6 +44,9 @@ class QueryResult:
     source: str               # "cache" | "warm" | "cold"
     rounds: int               # LP rounds this column cost (0 on cache hit)
     latency_s: float = 0.0    # filled by the scheduler/driver
+    # submit -> the batch entering the execute stage, before the engine
+    # lock: the time the query waited in the scheduler (filled by it)
+    queued_s: float = 0.0
 
     @property
     def global_candidates(self) -> np.ndarray:
