@@ -342,8 +342,67 @@ class NormalizedNetwork:
 
     # --------------------------------------------------------- COO assembly
     def to_coo(self) -> "HeteroCOO":
-        H, M = self.assemble_dense()
-        return HeteroCOO.from_dense(H, M, sizes=self.sizes)
+        """COO view of ``(H, M)``, extracted block by block.
+
+        The same arrays as ``HeteroCOO.from_dense(*self.assemble_dense())``
+        — edges in (dst, src) order, each block's own float64 weights —
+        without materialising an ``(N, N)`` matrix: only each block is
+        scanned for its nonzeros, which are offset into global ids (and,
+        for an association block, mirrored).
+        """
+        off = self.offsets
+        hom = [
+            (t, t, *_block_edges(s, off[t], off[t]))
+            for t, s in enumerate(self.S_homo)
+        ]
+        het = []
+        for (i, j), s in self.S_het.items():
+            dst, src, w = _block_edges(s, off[i], off[j])
+            het.append((i, j, dst, src, w))
+            het.append((j, i, src, dst, w))  # the mirror block, s.T
+        hs, hd, hw = _dst_major(het)
+        ms, md, mw = _dst_major(hom)
+        return HeteroCOO(
+            het_src=hs,
+            het_dst=hd,
+            het_w=hw,
+            hom_src=ms,
+            hom_dst=md,
+            hom_w=mw,
+            num_nodes=self.num_nodes,
+            sizes=list(self.sizes),
+        )
+
+
+def _block_edges(s, row_off: int, col_off: int):
+    """``(dst, src, w)`` of one block's nonzeros, row-major, global ids."""
+    if not s.any():  # an empty block: one cheap pass, no index arrays
+        empty = np.zeros(0, np.int32)
+        return empty, empty, np.zeros(0)
+    flat = np.flatnonzero(s)
+    r, c = np.divmod(flat, s.shape[1])
+    return (
+        (r + row_off).astype(np.int32),
+        (c + col_off).astype(np.int32),
+        s.ravel()[flat].astype(np.float64),
+    )
+
+
+def _dst_major(pieces):
+    """Concatenate per-block edges into one (dst, src)-ordered ``(src, dst, w)``.
+
+    ``pieces`` are ``(dst_type, src_type, dst, src, w)``, each listing the
+    edges into one dst in ascending src — true of a block's row-major
+    nonzeros and of their mirror alike.  Laid out by (dst type, src type),
+    a stable sort by dst keeps that order and the src-type order between
+    pieces, which together are src order.
+    """
+    pieces = sorted(pieces, key=lambda p: p[:2])
+    dst = np.concatenate([p[2] for p in pieces] or [np.zeros(0, np.int32)])
+    src = np.concatenate([p[3] for p in pieces] or [np.zeros(0, np.int32)])
+    w = np.concatenate([p[4] for p in pieces] or [np.zeros(0)])
+    order = np.argsort(dst, kind="stable")
+    return src[order], dst[order], w[order]
 
 
 @dataclasses.dataclass

@@ -237,28 +237,31 @@ def _tighten_buckets(buckets):
     Returns ``[(rows, nbr, wgt), ...]`` numpy triples, widest first.
     """
     rows_all = np.concatenate([b.rows for b in buckets])
-    nbr_all = [b.nbr[i] for b in buckets for i in range(b.nbr.shape[0])]
-    wgt_all = [b.wgt[i] for b in buckets for i in range(b.wgt.shape[0])]
-    widths = np.array([int((w != 0).sum()) for w in wgt_all])
+    # every row's nonzeros, left-packed in slot order, row after row
+    nz = [b.wgt != 0 for b in buckets]
+    widths = np.concatenate([m.sum(axis=1) for m in nz])
+    nbr_flat = np.concatenate([b.nbr[m] for b, m in zip(buckets, nz)])
+    wgt_flat = np.concatenate([b.wgt[m] for b, m in zip(buckets, nz)])
+    starts = np.cumsum(widths) - widths
     order = np.argsort(-widths, kind="stable")
+    neg = -widths[order]  # ascending
     out = []
     i, n = 0, len(order)
     while i < n:
-        wmax = max(int(widths[order[i]]), 1)
-        j = i + 1
-        while j < n and (
-            widths[order[j]] >= _TIGHTEN_SLACK * wmax
-            or j - i < _TIGHTEN_MIN_ROWS
-        ):
-            j += 1
+        wmax = max(int(-neg[i]), 1)
+        # widths fall along ``order``: the rows within slack are a prefix
+        j = int(np.searchsorted(neg, -_TIGHTEN_SLACK * wmax, side="right"))
+        j = min(max(j, i + _TIGHTEN_MIN_ROWS), n)
         bw = -(-wmax // _TIGHTEN_ALIGN) * _TIGHTEN_ALIGN
         sel = order[i:j]
+        cnt = widths[sel]
+        row = np.repeat(np.arange(len(sel)), cnt)
+        col = np.arange(row.size) - np.repeat(np.cumsum(cnt) - cnt, cnt)
+        src = np.repeat(starts[sel], cnt) + col
         nbr = np.zeros((len(sel), bw), dtype=np.int32)
         wgt = np.zeros((len(sel), bw), dtype=np.float32)
-        for k, r in enumerate(sel):
-            nz = np.flatnonzero(wgt_all[r])
-            nbr[k, : nz.size] = nbr_all[r][nz]
-            wgt[k, : nz.size] = wgt_all[r][nz]
+        nbr[row, col] = nbr_flat[src]
+        wgt[row, col] = wgt_flat[src]
         out.append((rows_all[sel], nbr, wgt))
         i = j
     return out
