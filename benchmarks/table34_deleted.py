@@ -23,7 +23,7 @@ def run(n_drug: int = 60, n_disease: int = 40, n_target: int = 30,
         n_clusters=6, seed=seed,
     ))
     net = dn.network
-    R = net.R[(0, 2)]
+    R = net.support((0, 2))
     rng = np.random.default_rng(seed)
     drugs = [int(d) for d in np.argwhere((R > 0).sum(axis=1) >= 3).ravel()]
     rng.shuffle(drugs)

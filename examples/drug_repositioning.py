@@ -54,7 +54,7 @@ def main() -> None:
 
     # ---- Table 3: deleted interaction --------------------------------------
     print("\n== deleted-interaction recovery ==")
-    R = net.R[(0, 2)]
+    R = net.support((0, 2))
     drug = int(np.argmax((R > 0).sum(axis=1) >= 3))
     target = int(np.argwhere(R[drug] > 0)[0][0])
     mask = np.zeros_like(R, dtype=bool)

@@ -49,7 +49,7 @@ def main() -> None:
 
     # 3. outputs: the ranking artifact + full interaction matrices
     drug = art.ranking["entity"]
-    known = np.argwhere(net.R[(0, 2)][drug] > 0).ravel()
+    known = np.argwhere(net.support((0, 2))[drug]).ravel()
     print(f"drug {drug}: known targets {known.tolist()}, "
           f"top-5 predicted {art.ranking['candidates']}")
 

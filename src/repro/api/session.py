@@ -82,7 +82,11 @@ class Session:
     def norm(self):
         """The one normalized view every stage shares (prepare-cache key)."""
         if self._norm is None:
-            self._norm = self.network.normalize()
+            net = self.network
+            with self.telemetry.trace_span("network.normalize") as span:
+                self._norm = net.normalize()
+                if span.id is not None:
+                    span.set(relations=net.num_relations, blocks=len(net.blocks))
         return self._norm
 
     def _trace_coupled_params(self, sc) -> Dict[str, Any]:

@@ -13,11 +13,15 @@ Note the DHLP algorithms update all subnetworks *simultaneously* (Jacobi)
 because every Giraph vertex runs the same program in a superstep, while the
 originals are sequential (Gauss–Seidel).  Both orderings converge to the same
 fixed point for fixed seeds (tests assert this); iteration counts differ.
+
+:func:`relation_mean_operator` and :func:`fixed_point` are the oracle for
+networks with several relations on one block: the operator built from the
+raw relation matrices alone, independent of ``HeteroNetwork``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -130,3 +134,67 @@ def run_all_seeds(
         outer = max(outer, r.outer_iters)
         inner += r.inner_iters
     return RefResult(np.concatenate(cols, axis=1), outer, inner)
+
+
+# --------------------------------------------------------------------------
+# Relation-mean operator (networks with several relations on one block)
+# --------------------------------------------------------------------------
+def _inv_sqrt_or_zero(d: np.ndarray) -> np.ndarray:
+    return np.where(d > 0, 1.0 / np.sqrt(np.where(d > 0, d, 1.0)), 0.0)
+
+
+def relation_mean_operator(
+    sizes: Sequence[int],
+    relations: Iterable[Tuple[int, int, np.ndarray]],
+    alpha: float = 0.5,
+    hetero_scale: Optional[float] = None,
+) -> np.ndarray:
+    """The dense ``(N, N)`` fused DHLP-2 operator of a network given as
+    relations, in plain float64 (the test oracle; no code of the program).
+
+    ``relations`` holds ``(i, j, M)``: a similarity relation of type ``i``
+    when ``i == j`` (symmetrized, ``(M + Mᵀ)/2``), else an association
+    relation between types ``i`` and ``j`` (``M`` of shape ``(n_i, n_j)``).
+    Each relation is normalized on its own — ``D^{-1/2} M D^{-1/2}``, or
+    ``D_r^{-1/2} M D_c^{-1/2}``, a zero degree giving a zero factor — and
+    each block is the mean of its relations.  The operator is then
+
+        A = α·S_hom + α·β·s·S_het,   β = 1 − α,   s = 1/(T − 1)
+
+    (``s`` = ``hetero_scale`` where given), with each association block
+    and its transpose in ``S_het``.
+    """
+    sizes = [int(n) for n in sizes]
+    T = len(sizes)
+    off = np.concatenate([[0], np.cumsum(sizes)]).astype(int)
+    beta = 1.0 - alpha
+    s = 1.0 / max(1, T - 1) if hetero_scale is None else hetero_scale
+    blocks: Dict[Tuple[int, int], List[np.ndarray]] = {}
+    for i, j, m in relations:
+        m = np.asarray(m.toarray() if hasattr(m, "toarray") else m, dtype=np.float64)
+        if i > j:
+            i, j, m = j, i, m.T
+        if i == j:
+            m = (m + m.T) / 2.0
+        row_deg, col_deg = m.sum(axis=1), m.sum(axis=0)
+        row_f, col_f = _inv_sqrt_or_zero(row_deg), _inv_sqrt_or_zero(col_deg)
+        blocks.setdefault((i, j), []).append(row_f[:, None] * m * col_f[None, :])
+    n = int(off[-1])
+    A = np.zeros((n, n))
+    for (i, j), mats in blocks.items():
+        mean = sum(mats) / len(mats)
+        ri, rj = slice(off[i], off[i + 1]), slice(off[j], off[j + 1])
+        if i == j:
+            A[ri, ri] += alpha * mean
+        else:
+            A[ri, rj] += alpha * beta * s * mean
+            A[rj, ri] += alpha * beta * s * mean.T
+    return A
+
+
+def fixed_point(A: np.ndarray, Y: np.ndarray, alpha: float = 0.5) -> np.ndarray:
+    """The exact fixed-seed fixed point ``F = β²Y + A·F`` (DHLP-1 with its
+    inner solve converged and DHLP-2 share it): ``(I − A)⁻¹ β² Y``."""
+    beta = 1.0 - alpha
+    rhs = beta * beta * np.asarray(Y, np.float64)
+    return np.linalg.solve(np.eye(A.shape[0]) - A, rhs)

@@ -103,10 +103,8 @@ def shape_class(num_nodes: int, nnz: int) -> str:
 
 
 def network_nnz(norm) -> int:
-    """Cheap nnz estimate off the normalized blocks (no COO assembly)."""
-    nnz = sum(int(np.count_nonzero(s)) for s in norm.S_homo)
-    nnz += 2 * sum(int(np.count_nonzero(s)) for s in norm.S_het.values())
-    return nnz
+    """Cheap nnz off the normalized blocks (no COO assembly)."""
+    return norm.operator_nnz
 
 
 def cache_path(cache_dir: Optional[os.PathLike] = None) -> Path:

@@ -66,14 +66,14 @@ def cross_validate(
     """Run k-fold CV on one association matrix.
 
     ``solver_fn(masked_net) -> scores`` must return the predicted score
-    matrix for ``pair`` (same shape as ``net.R[pair]``).  With
+    matrix for ``pair``, ``(n_i, n_j)`` for ``i < j``.  With
     ``positives`` (a boolean subset of the present edges — e.g. a
     scenario's planted truth), folds hide only those entries and the
     negative set excludes non-positive present edges (noise), so the
     protocol runs against ground truth on any T-type scenario.
     """
     i, j = min(pair), max(pair)
-    R = net.R[(i, j)]
+    R = net.support((i, j))  # the known associations, any relation
     if positives is None:
         positives = R > 0
     results: List[FoldResult] = []

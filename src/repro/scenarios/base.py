@@ -79,14 +79,12 @@ class ScenarioBundle:
 
     def __post_init__(self) -> None:
         i, j = self.eval_pair
-        if (i, j) not in self.network.R:
+        if i == j or (i, j) not in self.network.blocks:
             raise ValueError(f"eval_pair {(i, j)} has no association block")
         for pair, mask in self.truth.items():
-            if mask.shape != self.network.R[pair].shape:
-                raise ValueError(
-                    f"truth[{pair}] shape {mask.shape} != "
-                    f"{self.network.R[pair].shape}"
-                )
+            want = (self.network.sizes[pair[0]], self.network.sizes[pair[1]])
+            if mask.shape != want:
+                raise ValueError(f"truth[{pair}] shape {mask.shape} != {want}")
 
     def describe(self) -> Dict[str, Any]:
         net = self.network
@@ -97,7 +95,7 @@ class ScenarioBundle:
             "sizes": list(net.sizes),
             "nodes": net.num_nodes,
             "edges": net.num_edges,
-            "pairs": sorted(net.R),
+            "pairs": sorted(p for p in net.blocks if p[0] != p[1]),
             "eval_pair": tuple(self.eval_pair),
             "planted_positives": {
                 str(k): int(v.sum()) for k, v in sorted(self.truth.items())
@@ -167,7 +165,7 @@ def get_scenario(name: str) -> ScenarioInfo:
 # above this edge count are written.
 CACHE_MIN_EDGES = 200_000
 # bump when generator semantics change: stale cache entries must miss
-_CACHE_SALT = "v1"
+_CACHE_SALT = "v2"  # v2: HeteroNetwork holds relation blocks
 
 
 def cache_dir() -> str:

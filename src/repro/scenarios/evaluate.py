@@ -84,8 +84,8 @@ def make_recovery_problem(
     rows that lost one (subsampled to ``max_entities``)."""
     pair = bundle.eval_pair if pair is None else (min(pair), max(pair))
     net = bundle.network
-    R = net.R[pair]
-    planted = bundle.truth[pair] & (R > 0)
+    known = net.support(pair)
+    planted = bundle.truth[pair] & known
     pos = np.argwhere(planted)
     if len(pos) < 2:
         raise ValueError(f"pair {pair} has too few planted positives")
@@ -110,7 +110,7 @@ def make_recovery_problem(
         Y=Y,
         rows=rows,
         heldout=heldout,
-        negatives=(R == 0) & ~bundle.truth[pair],
+        negatives=~known & ~bundle.truth[pair],
         target_slice=slice(off_j, off_j + net.sizes[j]),
     )
 
@@ -210,7 +210,7 @@ def scenario_cross_validate(
 ) -> List[FoldResult]:
     """The Table 2 k-fold protocol against the scenario's planted truth."""
     pair = bundle.eval_pair if pair is None else (min(pair), max(pair))
-    positives = bundle.truth[pair] & (bundle.network.R[pair] > 0)
+    positives = bundle.truth[pair] & bundle.network.support(pair)
     return cross_validate(
         bundle.network,
         pair,

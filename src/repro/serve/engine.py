@@ -685,7 +685,8 @@ class LPServeEngine:
         cands = [o for o in by_type.get(t, ()) if o != node]
         if not cands:
             return None
-        sims = state.net.P[t][u, np.asarray(cands) - state.offsets[t]]
+        rows = state.net.relation_rows(t, t, u)
+        sims = np.sum(rows, axis=0)[np.asarray(cands) - state.offsets[t]]
         best = int(np.argmax(sims))
         if sims[best] <= 0.0:
             return None
@@ -705,12 +706,10 @@ class LPServeEngine:
         off = state.offsets[tt]
         scores = np.asarray(col[off : off + state.sizes[tt]], dtype=np.float64)
         exclude = np.zeros(scores.shape[0], dtype=bool)
-        if not spec.include_known:
-            R = state.net.R
-            if (t_ent, tt) in R:
-                exclude |= R[(t_ent, tt)][u] > 0
-            elif (tt, t_ent) in R:
-                exclude |= R[(tt, t_ent)][:, u] > 0
+        if not spec.include_known and t_ent != tt:
+            # a pair is known if any relation of its block holds it
+            for row in state.net.relation_rows(t_ent, tt, u):
+                exclude |= row > 0
         if t_ent == tt:
             exclude[u] = True  # an entity is not its own candidate
         cand = topk_exclusive(scores, spec.top_k, exclude)
@@ -740,9 +739,12 @@ class LPServeEngine:
             if delta.is_empty:
                 return self._state.version
             old = self._state
-            with trace_span(tel, "serve.delta.normalize"):
+            with trace_span(tel, "serve.delta.normalize") as span:
                 new_net = old.net.apply_delta(delta)
-                new = NetworkState.from_network(new_net, old.version + 1)
+                touched = old.net.touched_relations(delta)
+                norm = old.norm.renormalized(new_net, touched)
+                new = NetworkState.from_network(new_net, old.version + 1, norm=norm)
+                span.set(relations=len(touched))
             remap = None
             if delta.add_nodes:
                 remap = _make_remap(old, new)

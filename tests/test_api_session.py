@@ -103,8 +103,8 @@ def test_file_network_round_trip(tmp_path):
     net.save_npz(path)
     loaded = HeteroNetwork.load_npz(path)
     assert loaded.sizes == net.sizes
-    for (i, j), r in net.R.items():
-        np.testing.assert_array_equal(loaded.R[(i, j)], r)
+    for pair, name, m in net.relations():
+        np.testing.assert_array_equal(loaded.blocks[pair][name].toarray(), m.toarray())
     assert tuple(loaded.type_names) == tuple(net.type_names)
 
     spec = RunSpec(
@@ -266,7 +266,8 @@ def test_scenario_disk_cache_round_trip(tmp_path, monkeypatch):
     # second generation loads the pickle — identical bundle content
     second = sc.generate("bipartite", scale=0.25, seed=3)
     np.testing.assert_array_equal(
-        first.network.R[(0, 1)], second.network.R[(0, 1)]
+        first.network.blocks[(0, 1)][""].toarray(),
+        second.network.blocks[(0, 1)][""].toarray(),
     )
     # a different seed is a different cache key
     sc.generate("bipartite", scale=0.25, seed=4)
@@ -302,7 +303,7 @@ def test_bipartite_scenario_registered_and_recoverable():
     assert "bipartite" in sc.available_scenarios()
     bundle = sc.generate("bipartite", scale=0.25, seed=0)
     assert bundle.network.num_types == 2
-    assert set(bundle.network.R) == {(0, 1)}
+    assert {p for p in bundle.network.blocks if p[0] != p[1]} == {(0, 1)}
     out = sc.recovery_auc(bundle, "dense", max_entities=6)
     assert out["recovery_auc"] > 0.8
 

@@ -70,7 +70,8 @@ class TestContainer:
 
     def test_similarity_symmetrized(self):
         net = small_net()
-        for p in net.P:
+        for t in range(net.num_types):
+            p = net.blocks[(t, t)][""].toarray()
             np.testing.assert_allclose(p, p.T)
 
     def test_transposed_R_canonicalized(self):
@@ -78,7 +79,7 @@ class TestContainer:
         P = [np.eye(3), np.eye(2)]
         r = rng.random((2, 3))
         net = HeteroNetwork(P=P, R={(1, 0): r})
-        np.testing.assert_allclose(net.R[(0, 1)], r.T)
+        np.testing.assert_allclose(net.blocks[(0, 1)][""].toarray(), r.T)
 
     def test_assembly_disjoint_support(self):
         norm = small_net().normalize()
@@ -96,15 +97,16 @@ class TestContainer:
 
     def test_fold_masking(self):
         net = small_net()
-        R = net.R[(0, 2)]
-        mask = np.zeros_like(R, dtype=bool)
-        pos = np.argwhere(R > 0)
+        R = net.blocks[(0, 2)][""]
+        mask = np.zeros(R.shape, dtype=bool)
+        pos = np.argwhere(R.toarray() > 0)
         assert len(pos) > 0
         mask[pos[0][0], pos[0][1]] = True
         masked = net.with_masked_fold((0, 2), mask)
-        assert masked.R[(0, 2)][pos[0][0], pos[0][1]] == 0
+        assert masked.blocks[(0, 2)][""][pos[0][0], pos[0][1]] == 0
+        assert masked.blocks[(0, 2)][""].nnz == R.nnz - 1
         # original untouched
-        assert net.R[(0, 2)][pos[0][0], pos[0][1]] > 0
+        assert net.blocks[(0, 2)][""][pos[0][0], pos[0][1]] > 0
 
     def test_num_edges_counts_both_directions_of_R(self):
         net = HeteroNetwork(

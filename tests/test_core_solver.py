@@ -63,7 +63,7 @@ class TestFixedPoint:
 
     def test_inner_solution_closed_form(self, net):
         norm = net.normalize()
-        S = norm.S_homo[0]
+        S = norm.S_homo[0].toarray()
         rng = np.random.default_rng(3)
         yp = rng.random((S.shape[0], 4))
         f = dhlp1_inner_solution(S, yp, 0.5)

@@ -103,11 +103,12 @@ def test_permutation_equivariance(seed):
     rng = np.random.default_rng(seed)
     net = build_net(seed, (6, 5, 4), 0.5)
     perm = rng.permutation(6)
-    P2 = [net.P[0][np.ix_(perm, perm)], net.P[1], net.P[2]]
+    B = {k: rels[""].toarray() for k, rels in net.blocks.items()}
+    P2 = [B[(0, 0)][np.ix_(perm, perm)], B[(1, 1)], B[(2, 2)]]
     R2 = {
-        (0, 1): net.R[(0, 1)][perm],
-        (0, 2): net.R[(0, 2)][perm],
-        (1, 2): net.R[(1, 2)],
+        (0, 1): B[(0, 1)][perm],
+        (0, 2): B[(0, 2)][perm],
+        (1, 2): B[(1, 2)],
     }
     net2 = HeteroNetwork(P=P2, R=R2)
     cfg = LPConfig(alg="dhlp2", seed_mode="fixed", sigma=1e-7, max_iter=5000)

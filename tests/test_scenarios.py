@@ -21,6 +21,15 @@ from repro.scenarios.generators import (
 )
 
 
+def _dense(net, pair):
+    """A block of one relation (the generators' networks) as an array."""
+    return net.blocks[pair][""].toarray()
+
+
+def _pairs(net):
+    return {p for p in net.blocks if p[0] != p[1]}
+
+
 class TestRegistry:
     def test_at_least_five_scenarios(self):
         names = sc.available_scenarios()
@@ -47,11 +56,11 @@ class TestRegistry:
             scale = 0.02 if name == "powerlaw" else 0.25
             b = sc.generate(name, scale=scale, seed=0)
             net = b.network
-            assert b.eval_pair in net.R
+            assert b.eval_pair in _pairs(net)
             for pair, mask in b.truth.items():
-                assert mask.shape == net.R[pair].shape
+                assert mask.shape == net.support(pair).shape
                 # planted positives are present edges
-                assert not np.any(mask & (net.R[pair] == 0)), (name, pair)
+                assert not np.any(mask & ~net.support(pair)), (name, pair)
             d = b.describe()
             assert d["nodes"] == net.num_nodes
 
@@ -63,10 +72,9 @@ class TestDrugnetAdapter:
         spec = DrugNetSpec(n_drug=30, n_disease=20, n_target=15, seed=7)
         dn = make_drugnet(spec)
         pk = planted_kpartite(spec.to_kpartite())
-        for a, b in zip(dn.network.P, pk.network.P):
-            np.testing.assert_array_equal(a, b)
-        for k in dn.network.R:
-            np.testing.assert_array_equal(dn.network.R[k], pk.network.R[k])
+        assert dn.network.blocks.keys() == pk.network.blocks.keys()
+        for k in dn.network.blocks:
+            np.testing.assert_array_equal(_dense(dn.network, k), _dense(pk.network, k))
         assert dn.truth is not None
         np.testing.assert_array_equal(dn.truth[(0, 2)], pk.truth[(0, 2)])
 
@@ -76,8 +84,9 @@ class TestDrugnetAdapter:
         dn = make_drugnet(
             DrugNetSpec(n_drug=40, n_disease=30, n_target=20, seed=3)
         )
-        p_sq = float(sum((p**2).sum() for p in dn.network.P))
-        r_sum = float(sum(r.sum() for r in dn.network.R.values()))
+        net = dn.network
+        p_sq = float(sum((_dense(net, (t, t)) ** 2).sum() for t in range(net.num_types)))
+        r_sum = float(sum(_dense(net, k).sum() for k in _pairs(net)))
         assert repr(p_sq) == "233.22902809050655"
         assert r_sum == 209.0
 
@@ -85,7 +94,7 @@ class TestDrugnetAdapter:
         b = sc.generate("bio_tri", scale=1.0, seed=0)
         dn = make_drugnet(DrugNetSpec(seed=0))
         np.testing.assert_array_equal(
-            b.network.R[(0, 2)], dn.network.R[(0, 2)]
+            _dense(b.network, (0, 2)), _dense(dn.network, (0, 2))
         )
 
 
@@ -118,7 +127,7 @@ class TestGenerators:
             seed=0,
         )
         pk = planted_kpartite(spec)
-        deg = np.count_nonzero(pk.network.P[0], axis=1)
+        deg = np.count_nonzero(_dense(pk.network, (0, 0)), axis=1)
         # hubs: max degree far above the mean — the cross-cluster support
         # means the tail is not capped at the cluster size n/k
         assert deg.max() > 4 * deg.mean()
@@ -143,7 +152,7 @@ class TestGenerators:
         t = b.network.num_types
         assert t == 5
         all_pairs = {(i, j) for i in range(t) for j in range(i + 1, t)}
-        assert set(b.network.R) < all_pairs  # strictly sparser schema
+        assert _pairs(b.network) < all_pairs  # strictly sparser schema
 
 
 class TestArrivals:
@@ -224,14 +233,14 @@ class TestPlantedTruthEval:
 
     def test_cv_positives_must_be_present_edges(self, k5):
         pair = k5.eval_pair
-        R = k5.network.R[pair]
+        R = _dense(k5.network, pair)
         bad = np.ones_like(R, dtype=bool)  # claims absent edges as positive
         with pytest.raises(ValueError, match="present"):
             list(kfold_masks(R, k=2, positives=bad))
 
     def test_cv_folds_hide_only_planted_entries(self, k5):
         pair = k5.eval_pair
-        R = k5.network.R[pair]
+        R = _dense(k5.network, pair)
         planted = k5.truth[pair] & (R > 0)
         union = np.zeros_like(planted)
         for mask in kfold_masks(R, k=3, positives=planted):
@@ -243,7 +252,7 @@ class TestPlantedTruthEval:
         """A noise edge (present, not planted) is neither hidden nor a
         negative: spiking its score must not change any fold metric."""
         pair = k5.eval_pair
-        R = k5.network.R[pair]
+        R = _dense(k5.network, pair)
         planted = k5.truth[pair] & (R > 0)
         noise = (R > 0) & ~planted
         if not noise.any():
@@ -269,12 +278,12 @@ class TestStreamingScenario:
         arriving = b.meta["arriving_truth"]
         assert int(arriving.sum()) == b.meta["heldout_edges"]
         # t=0 network lacks the held-out edges; truth agrees
-        assert not np.any((b.network.R[pair] > 0) & arriving)
+        assert not np.any(b.network.support(pair) & arriving)
         assert not np.any(b.truth[pair] & arriving)
         net = b.network
         for td in b.deltas:
             net = net.apply_delta(td.delta)
-        R_after = net.R[pair]
+        R_after = _dense(net, pair)
         rows, cols = np.nonzero(arriving)
         assert np.all(R_after[rows, cols] > 0)
         # delta times are ordered and inside the trace horizon

@@ -125,7 +125,7 @@ class TestSchedulerCoalescing:
         assert calls == [10]          # ONE solver call for ten queries
         for e, fut in enumerate(futs):
             res = fut.result()
-            unknown = int(np.sum(net.R[(0, 2)][e] == 0))
+            unknown = int(np.sum(~net.support((0, 2))[e]))
             assert res.candidates.size == min(4, unknown)
             # scores come back descending
             assert np.all(np.diff(res.scores) <= 0)
@@ -247,10 +247,14 @@ class TestColumnCache:
         net = small_net()
         # make drugs 0 and 1 near-identical: strong mutual similarity and
         # the same association rows, so their label columns nearly coincide
-        net.P[0][0, 1] = net.P[0][1, 0] = 1.0
+        B = {k: rels[""].toarray() for k, rels in net.blocks.items()}
+        B[(0, 0)][0, 1] = B[(0, 0)][1, 0] = 1.0
         for pair in [(0, 1), (0, 2)]:
-            net.R[pair][1] = net.R[pair][0]
-        net = HeteroNetwork(P=net.P, R=net.R)
+            B[pair][1] = B[pair][0]
+        net = HeteroNetwork(
+            P=[B[(t, t)] for t in range(net.num_types)],
+            R={k: b for k, b in B.items() if k[0] != k[1]},
+        )
         engine = LPServeEngine(net, serve_cfg())
         cold = engine.query(QuerySpec(entity=0, target_type=2))
         warm = engine.query(QuerySpec(entity=1, target_type=2))
@@ -405,15 +409,17 @@ class TestGraphDelta:
             sim=[(0, 3, 4, 0.5)],
         )
         new = net.apply_delta(delta)
-        assert new.R[(0, 2)][1, 2] == 1.0
-        assert new.P[0][3, 4] == 0.5 and new.P[0][4, 3] == 0.5
+        R, P = new.blocks[(0, 2)][""], new.blocks[(0, 0)][""]
+        assert R[1, 2] == 1.0
+        assert P[3, 4] == 0.5 and P[4, 3] == 0.5
         # original untouched
-        assert net.P[0][3, 4] != 0.5 or net.R[(0, 2)][1, 2] != 1.0
+        R, P = net.blocks[(0, 2)][""], net.blocks[(0, 0)][""]
+        assert P[3, 4] != 0.5 or R[1, 2] != 1.0
 
     def test_reversed_pair_orientation(self):
         net = small_net()
         new = net.apply_delta(GraphDelta(assoc=[((2, 0), 3, 1, 1.0)]))
-        assert new.R[(0, 2)][1, 3] == 1.0
+        assert new.blocks[(0, 2)][""][1, 3] == 1.0
 
     def test_touched_types(self):
         delta = GraphDelta(assoc=[((0, 2), 0, 0, 1.0)], add_nodes={1: 1})
@@ -442,7 +448,7 @@ class TestRanking:
         net = small_net()
         engine = LPServeEngine(net, serve_cfg())
         res = engine.query(QuerySpec(entity=0, target_type=2, top_k=50))
-        known = np.nonzero(net.R[(0, 2)][0] > 0)[0]
+        known = np.nonzero(net.support((0, 2))[0])[0]
         assert not set(res.candidates.tolist()) & set(known.tolist())
         inc = engine.query(
             QuerySpec(entity=0, target_type=2, top_k=50, include_known=True)

@@ -39,7 +39,7 @@ def _pick_drug_with_targets(R, min_t=3):
 @pytest.mark.parametrize("alg", ["dhlp1", "dhlp2"])
 def test_deleted_interaction_recovery(drugnet, alg):
     net = drugnet.network
-    R = net.R[(0, 2)]
+    R = net.support((0, 2))
     drug = _pick_drug_with_targets(R)
     target = int(np.argwhere(R[drug] > 0)[0][0])
     mask = np.zeros_like(R, dtype=bool)
@@ -55,7 +55,7 @@ def test_deleted_interaction_recovery(drugnet, alg):
 @pytest.mark.parametrize("alg", ["dhlp1", "dhlp2"])
 def test_pseudo_new_drug_recovery(drugnet, alg):
     net = drugnet.network
-    R = net.R[(0, 2)]
+    R = net.support((0, 2))
     drug = _pick_drug_with_targets(R)
     true_targets = np.argwhere(R[drug] > 0).ravel()
     mask = np.zeros_like(R, dtype=bool)
